@@ -32,6 +32,7 @@ from repro.configs.base import EliteKVConfig
 from repro.core import convert, lrd, ropelite
 from repro.core.cache import cache_ratio, model_cache_floats_per_token
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.runtime import train_loop
 
@@ -832,4 +833,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

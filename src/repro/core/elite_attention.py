@@ -350,6 +350,15 @@ def _pin(mesh, x, *spec):
         x, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(*spec)))
 
 
+def _pin_projections(mesh, *xs):
+    """Pin per-head projections to *replicated* under TP, so every device
+    computes them at single-device shapes.  Left free, GSPMD shards them
+    along heads (from the head-sharded ``k_e`` pages and the shard_map
+    inputs), and the TPU compiles those narrower dots with a different float
+    order than single-device — which breaks the bit-identity serving wall."""
+    return tuple(_pin(mesh, x) for x in xs)
+
+
 def _gather_prefix(pages, params, cfg, block_tables, block_size: int, dt):
     """Materialize K/V for a sequence's cached *prefix* from pool pages.
 
@@ -435,6 +444,8 @@ def apply_prefill_paged(params, cfg, buffers, x, positions, pages,
     from repro.models.attention import _attend
     q, k, v, k_e, c_k, c_v = _materialized(params, cfg, buffers, x, positions,
                                            constrain)
+    if _tp(mesh, tp_axis) > 1:
+        q, k, v, k_e = _pin_projections(mesh, q, k, v, k_e)
     B, S = x.shape[:2]
     if "k_e_scale" in pages:
         # int8 pool: in-chunk attention must see exactly what a later pool
@@ -512,6 +523,8 @@ def apply_verify_paged(params, cfg, buffers, x, pages, slot_mapping,
     k_e_new = jnp.einsum("bsd,dhe->bshe", x, params["wk_e"].astype(dt))
     k_e_new = rope_lib.apply_elite_rope(k_e_new, pos, buffers["elite_freqs"])
     c_k_new, c_v_new = _latents(params, cfg, x)
+    if _tp(mesh, tp_axis) > 1:
+        q_e, q_lat, k_e_new = _pin_projections(mesh, q_e, q_lat, k_e_new)
     new_pages = _scatter_pages(
         pages, k_e_new.reshape(B * W, *k_e_new.shape[2:]),
         c_k_new.reshape(B * W, -1), c_v_new.reshape(B * W, -1),
@@ -578,6 +591,8 @@ def apply_decode_paged(params, cfg, buffers, x, pages, slot_mapping,
     k_e_new = jnp.einsum("bsd,dhe->bshe", x, params["wk_e"].astype(dt))
     k_e_new = rope_lib.apply_elite_rope(k_e_new, pos, buffers["elite_freqs"])
     c_k_new, c_v_new = _latents(params, cfg, x)
+    if _tp(mesh, tp_axis) > 1:
+        q_e, q_lat, k_e_new = _pin_projections(mesh, q_e, q_lat, k_e_new)
     new_pages = _scatter_pages(pages, k_e_new[:, 0], c_k_new[:, 0],
                                c_v_new[:, 0], slot_mapping)
 

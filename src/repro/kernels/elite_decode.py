@@ -1,6 +1,6 @@
-"""Fused EliteKV decode-attention Pallas kernel (the paper's serving hot-spot).
+"""Fused EliteKV decode-attention Pallas kernels (the paper's serving hot-spot).
 
-One pass over the *compressed* cache computes, per (batch, kv-head):
+One pass over the *compressed* cache computes, for every query head of a lane:
 
     s   = q_e · K_eᵀ + q_lat · C_kᵀ          (rotary-elite + latent scores)
     p   = online_softmax(s · scale)           (masked by per-sequence length)
@@ -12,15 +12,25 @@ once".  Because this kernel reads only the 2r·n_kv + d_ckv compressed stream
 paper's compression ratio — and fusing both score paths means the latent C is
 read once and serves s_lat *and* the output GEMM.
 
-VMEM tiling: grid (B, n_kv, S/block_s); per step the working set is
-  K_e block [block_s, 2r]  +  C_k/C_v blocks [block_s, d_c]
-  + accumulators [G, d_c], [G, 1] ×2         (scratch, persists over S steps)
-block_s=512, d_c=512, bf16 → ~1.1 MB ≪ 16 MB VMEM.  d_c and block_s are
-128-multiples (MXU-aligned); the 2r rotary GEMM rides lane padding (≤64).
-Per-sequence lengths arrive via scalar prefetch (ragged serving batches).
+Layout (what the TPU lowering accepts): the grid is (B, cache blocks), and
+one step holds ALL heads of a lane against one block, so every block's last
+two dims are whole array dims and no step slices one kv head out of the
+second-minor axis.
+  - K_e blocks are [block, nkv·2r]: the pool's [slots, nkv, 2r] seen flat.
+    The rotary query arrives block-diagonal, [nh, nkv·2r], with head n's 2r
+    dims in the lanes of its kv head n // G and zeros elsewhere
+    (``_block_diag_q_e``), so one dot scores every head's elite part.
+  - C_k / C_v blocks are [block, d_c], read once per lane and block and
+    shared by all nh heads (the latent is head-shared).
+  - int8 scales are viewed as [n_blocks, 1, block]: they scale the score
+    columns and the softmax weights along lanes, never a page's rows.
+Per step the working set is the lane's queries [rows, nkv·2r + d_c], one
+block [block, nkv·2r + 2·d_c] and the accumulators [rows, d_c] — tens of KiB
+at published widths.  Lengths, block tables and selections arrive via
+scalar prefetch (ragged serving batches).
 
-Final stage of the docs/architecture.md pipeline: the streams this kernel
-reads are produced by RoPElite selection (core/ropelite.py) + J-LRD
+Final stage of the docs/architecture.md pipeline: the streams these kernels
+read are produced by RoPElite selection (core/ropelite.py) + J-LRD
 factorization (core/lrd.py) and live in the paged pool docs/serving.md
 describes.
 """
@@ -36,54 +46,104 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _block_diag_q_e(q_e, nkv: int):
+    """[..., nh, 2r] → [..., nh, nkv·2r]: head n's rotary query sits in the
+    lanes of its kv head n // G, zeros elsewhere.  A dot of this against a
+    flat K_e block [block, nkv·2r] equals each head's dot with its own kv
+    head (the extra terms are exact zeros)."""
+    nh, r2 = q_e.shape[-2:]
+    own = jnp.arange(nh)[:, None] // (nh // nkv) == jnp.arange(nkv)[None, :]
+    q_bd = q_e[..., :, None, :] * own[:, :, None].astype(q_e.dtype)
+    return q_bd.reshape(q_e.shape[:-2] + (nh, nkv * r2))
+
+
+def _flat_pages(block_size: int, k_e_pages, c_k_pages, c_v_pages, *scales):
+    """Flat pool streams → per-block views (free reshapes): K_e
+    [n_blocks, block, nkv·2r], C_k/C_v [n_blocks, block, d_c], int8 scales
+    [n_blocks, 1, block]."""
+    n_slots = k_e_pages.shape[0]
+    assert n_slots % block_size == 0, (k_e_pages.shape, block_size)
+    nb = n_slots // block_size
+    return (k_e_pages.reshape(nb, block_size, -1),
+            c_k_pages.reshape(nb, block_size, -1),
+            c_v_pages.reshape(nb, block_size, -1),
+            *(s.reshape(nb, 1, block_size) for s in scales))
+
+
+def _nt(a, b):
+    """a [m, k] · b [n, k]ᵀ → [m, n] f32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _scores(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, scale: float):
+    """Scaled scores [rows, block] of the lane's query rows against one
+    block of an f32/bf16 pool."""
+    s = _nt(q_e_ref[0], k_e_ref[0])
+    s += _nt(q_lat_ref[0], c_k_ref[0])
+    return s * scale
+
+
+def _scores_q8(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, k_s_ref, ck_s_ref,
+               scale: float):
+    """``_scores`` over int8 pages: dequantization is fused as a per-slot
+    scale on each score column (``q·(k·s) = (q·k)·s``) — the HBM read stays
+    one byte per element, the math stays f32."""
+    s = _nt(q_e_ref[0], k_e_ref[0].astype(jnp.float32)) * k_s_ref[0]
+    s += _nt(q_lat_ref[0], c_k_ref[0].astype(jnp.float32)) * ck_s_ref[0]
+    return s * scale
+
+
+def _accumulate(s, c_v, acc_ref, m_ref, l_ref, v_scale=None):
+    """Online-softmax update of (acc, m, l) with one block of masked scores
+    ``s [rows, block]`` and values ``c_v [block, d_c]``; ``v_scale [1, block]``
+    (int8 pools) scales the weights of each value row."""
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = m_new
+    w = p.astype(c_v.dtype) if v_scale is None else p * v_scale
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        w, c_v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _finish(o_ref, acc_ref, l_ref):
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
 def _kernel(lengths_ref,                  # scalar-prefetch [B] int32
             q_e_ref, q_lat_ref, k_e_ref, c_k_ref, c_v_ref,
             o_ref,
             acc_ref, m_ref, l_ref,
             *, block_s: int, scale: float, n_blocks: int):
     b = pl.program_id(0)
-    sb = pl.program_id(2)
+    sb = pl.program_id(1)
 
     @pl.when(sb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
     length = lengths_ref[b]
     start = sb * block_s
 
     @pl.when(start < length)
     def _step():
-        q_e = q_e_ref[0, 0]                           # [G, 2r]
-        q_lat = q_lat_ref[0, 0]                       # [G, d_c]
-        k_e = k_e_ref[0, :, 0, :]                     # [block_s, 2r]
-        c_k = c_k_ref[0]                              # [block_s, d_c]
-        s = jax.lax.dot_general(
-            q_e, k_e, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, block_s]
-        s += jax.lax.dot_general(
-            q_lat, c_k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s *= scale
+        s = _scores(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, scale)
         pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(c_v_ref.dtype), c_v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, d_c]
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        _accumulate(jnp.where(pos < length, s, NEG_INF), c_v_ref[0],
+                    acc_ref, m_ref, l_ref)
 
     @pl.when(sb == n_blocks - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    def _():
+        _finish(o_ref, acc_ref, l_ref)
 
 
 def elite_decode(q_e, q_lat, k_e, c_k, c_v, lengths, q_group: int,
@@ -96,98 +156,97 @@ def elite_decode(q_e, q_lat, k_e, c_k, c_v, lengths, q_group: int,
     B, nh, r2 = q_e.shape
     S, nkv = k_e.shape[1], k_e.shape[2]
     d_c = c_k.shape[-1]
-    G = q_group
-    assert nh == nkv * G, (nh, nkv, G)
+    assert nh == nkv * q_group, (nh, nkv, q_group)
     block_s = min(block_s, S)
     assert S % block_s == 0, (S, block_s)
     n_blocks = S // block_s
+    K = nkv * r2
 
-    q_e_g = q_e.reshape(B, nkv, G, r2)
-    q_lat_g = q_lat.reshape(B, nkv, G, d_c)
-
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_kernel, block_s=block_s, scale=scale, n_blocks=n_blocks),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, nkv, n_blocks),
+            grid=(B, n_blocks),
             in_specs=[
-                pl.BlockSpec((1, 1, G, r2), lambda b, h, s, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_s, 1, r2), lambda b, h, s, L: (b, s, h, 0)),
-                pl.BlockSpec((1, block_s, d_c), lambda b, h, s, L: (b, s, 0)),
-                pl.BlockSpec((1, block_s, d_c), lambda b, h, s, L: (b, s, 0)),
+                pl.BlockSpec((1, nh, K), lambda b, s, L: (b, 0, 0)),
+                pl.BlockSpec((1, nh, d_c), lambda b, s, L: (b, 0, 0)),
+                pl.BlockSpec((1, block_s, K), lambda b, s, L: (b, s, 0)),
+                pl.BlockSpec((1, block_s, d_c), lambda b, s, L: (b, s, 0)),
+                pl.BlockSpec((1, block_s, d_c), lambda b, s, L: (b, s, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, L: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, nh, d_c), lambda b, s, L: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G, d_c), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((nh, d_c), jnp.float32),
+                pltpu.VMEM((nh, 1), jnp.float32),
+                pltpu.VMEM((nh, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, nkv, G, d_c), c_v.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nh, d_c), c_v.dtype),
         interpret=interpret,
         name="elite_decode",
-    )(lengths, q_e_g, q_lat_g, k_e, c_k, c_v)
-    return out.reshape(B, nh, d_c)
+    )(lengths, _block_diag_q_e(q_e, nkv), q_lat, k_e.reshape(B, S, K), c_k, c_v)
 
 
 # ---------------------------------------------------------------------------
-# paged decode: the cache lives in a block pool, sequences own block chains
+# paged kernels: the cache lives in a block pool, sequences own block chains
 # ---------------------------------------------------------------------------
+
+def _paged_call(kernel, name: str, scalars, q_e, q_lat, blocks,
+                out_dtype, interpret: bool):
+    """The ``pallas_call`` every paged kernel shares.  Grid (B, walk): step
+    (b, s) holds lane b's query rows and pool block ``scalars[0][b, s]`` —
+    the first scalar-prefetch operand is the block table (or the sparse
+    selection table), so the index maps pull pages straight from the pool
+    and no gather ever materializes a sequence.  ``blocks`` are the
+    ``_flat_pages`` views; q_e [B, rows, nkv·2r] (block-diagonal), q_lat
+    [B, rows, d_c] → o [B, rows, d_c]."""
+    B, rows, K = q_e.shape
+    d_c = q_lat.shape[-1]
+    block_size = blocks[0].shape[1]
+
+    def lane(w):
+        return pl.BlockSpec((1, rows, w), lambda b, s, *_: (b, 0, 0))
+
+    def page(shape):
+        return pl.BlockSpec((1,) + shape,
+                            lambda b, s, tbl, *_: (tbl[b, s], 0, 0))
+
+    in_specs = [lane(K), lane(d_c), page((block_size, K)),
+                page((block_size, d_c)), page((block_size, d_c))]
+    in_specs += [page((1, block_size))] * (len(blocks) - 3)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(B, scalars[0].shape[1]),
+            in_specs=in_specs,
+            out_specs=lane(d_c),
+            scratch_shapes=[
+                pltpu.VMEM((rows, d_c), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, rows, d_c), out_dtype),
+        interpret=interpret,
+        name=name,
+    )(*scalars, q_e, q_lat, *blocks)
+
+
+def _check_heads(q_e, k_e_pages, q_group: int) -> int:
+    nh, nkv = q_e.shape[-2], k_e_pages.shape[1]
+    assert nh == nkv * q_group, (nh, nkv, q_group)
+    return nkv
+
 
 def _paged_kernel(block_tables_ref,           # scalar-prefetch [B, mb] int32
-                  lengths_ref,                # scalar-prefetch [B] int32
-                  q_e_ref, q_lat_ref, k_e_ref, c_k_ref, c_v_ref,
-                  o_ref,
-                  acc_ref, m_ref, l_ref,
-                  *, block_size: int, scale: float, max_blocks: int):
-    """Same online softmax as ``_kernel``; grid dim 2 walks the *block table*
-    instead of a contiguous S axis — the BlockSpec index maps below pull page
-    ``block_tables[b, sb]`` straight from the pool, so no gather ever
-    materializes the sequence contiguously."""
-    b = pl.program_id(0)
-    sb = pl.program_id(2)
-
-    @pl.when(sb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    length = lengths_ref[b]
-    start = sb * block_size
-
-    @pl.when(start < length)
-    def _step():
-        q_e = q_e_ref[0, 0]                           # [G, 2r]
-        q_lat = q_lat_ref[0, 0]                       # [G, d_c]
-        k_e = k_e_ref[0, :, 0, :]                     # [block_size, 2r]
-        c_k = c_k_ref[0]                              # [block_size, d_c]
-        s = jax.lax.dot_general(
-            q_e, k_e, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, block_size]
-        s += jax.lax.dot_general(
-            q_lat, c_k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s *= scale
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(c_v_ref.dtype), c_v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, d_c]
-        acc_ref[...] = acc_ref[...] * alpha + pv
-
-    @pl.when(sb == max_blocks - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+                  *refs, block_size: int, scale: float, max_blocks: int):
+    """``_kernel`` with grid dim 1 walking the *block table* instead of a
+    contiguous S axis: only the BlockSpec index maps read the table, pulling
+    page ``block_tables[b, sb]`` straight from the pool, so no gather ever
+    materializes the sequence."""
+    del block_tables_ref
+    _kernel(*refs, block_s=block_size, scale=scale, n_blocks=max_blocks)
 
 
 def elite_decode_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -199,55 +258,20 @@ def elite_decode_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     c_k/c_v_pages [n_slots,d_c], block_tables [B,mb] int32, lengths [B] int32
     →  o [B,nh,d_c].  Length-0 sequences (empty slots) produce zeros.
     """
-    B, nh, r2 = q_e.shape
-    nkv = k_e_pages.shape[1]
-    d_c = c_k_pages.shape[-1]
-    G = q_group
-    assert nh == nkv * G, (nh, nkv, G)
-    assert k_e_pages.shape[0] % block_size == 0, (k_e_pages.shape, block_size)
-    n_blocks_pool = k_e_pages.shape[0] // block_size
-    mb = block_tables.shape[1]
-    assert block_tables.shape == (B, mb) and lengths.shape == (B,)
-
-    q_e_g = q_e.reshape(B, nkv, G, r2)
-    q_lat_g = q_lat.reshape(B, nkv, G, d_c)
-    k_e_p = k_e_pages.reshape(n_blocks_pool, block_size, nkv, r2)
-    c_k_p = c_k_pages.reshape(n_blocks_pool, block_size, d_c)
-    c_v_p = c_v_pages.reshape(n_blocks_pool, block_size, d_c)
-
-    out = pl.pallas_call(
+    nkv = _check_heads(q_e, k_e_pages, q_group)
+    B, mb = block_tables.shape
+    assert q_e.shape[0] == B and lengths.shape == (B,)
+    return _paged_call(
         functools.partial(_paged_kernel, block_size=block_size, scale=scale,
                           max_blocks=mb),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, nkv, mb),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, r2), lambda b, h, s, bt, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, bt, L: (b, h, 0, 0)),
-                # pool pages, indexed through the prefetched block table
-                pl.BlockSpec((1, block_size, 1, r2),
-                             lambda b, h, s, bt, L: (bt[b, s], 0, h, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, bt, L: (bt[b, s], 0, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, bt, L: (bt[b, s], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, bt, L: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, d_c), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, nkv, G, d_c), c_v_pages.dtype),
-        interpret=interpret,
-        name="elite_decode_paged",
-    )(block_tables, lengths, q_e_g, q_lat_g, k_e_p, c_k_p, c_v_p)
-    return out.reshape(B, nh, d_c)
+        "elite_decode_paged", (block_tables, lengths),
+        _block_diag_q_e(q_e, nkv), q_lat,
+        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages),
+        c_v_pages.dtype, interpret)
 
 
 # ---------------------------------------------------------------------------
-# paged decode over an int8 pool: fused in-register dequantization
+# paged decode over an int8 pool: fused dequantization
 # ---------------------------------------------------------------------------
 
 def _paged_kernel_q8(block_tables_ref,        # scalar-prefetch [B, mb] int32
@@ -257,58 +281,30 @@ def _paged_kernel_q8(block_tables_ref,        # scalar-prefetch [B, mb] int32
                      o_ref,
                      acc_ref, m_ref, l_ref,
                      *, block_size: int, scale: float, max_blocks: int):
-    """``_paged_kernel`` over int8 pages: the same block-table walk also pulls
-    each page's per-slot f32 scales, and every stream is dequantized
-    in-register (``int8 → f32 · scale``) right after the load — the HBM read
-    stays one byte per element, the math stays f32."""
+    """``_paged_kernel`` over int8 pages: the same block-table walk also
+    pulls each block's per-slot f32 scales (``_scores_q8``)."""
     b = pl.program_id(0)
-    sb = pl.program_id(2)
+    sb = pl.program_id(1)
 
     @pl.when(sb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
     length = lengths_ref[b]
     start = sb * block_size
 
     @pl.when(start < length)
     def _step():
-        q_e = q_e_ref[0, 0]                           # [G, 2r]
-        q_lat = q_lat_ref[0, 0]                       # [G, d_c]
-        k_s = k_s_ref[0]                              # [block_size]
-        ck_s = ck_s_ref[0]
-        k_e = k_e_ref[0, :, 0, :].astype(jnp.float32) \
-            * k_s[:, None]                            # [block_size, 2r]
-        c_k = c_k_ref[0].astype(jnp.float32) \
-            * ck_s[:, None]                           # [block_size, d_c]
-        s = jax.lax.dot_general(
-            q_e, k_e, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, block_size]
-        s += jax.lax.dot_general(
-            q_lat, c_k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s *= scale
+        s = _scores_q8(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, k_s_ref,
+                       ck_s_ref, scale)
         pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        c_v = c_v_ref[0].astype(jnp.float32) * cv_s_ref[0][:, None]
-        pv = jax.lax.dot_general(
-            p, c_v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, d_c]
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        _accumulate(jnp.where(pos < length, s, NEG_INF),
+                    c_v_ref[0].astype(jnp.float32), acc_ref, m_ref, l_ref,
+                    cv_s_ref[0])
 
     @pl.when(sb == max_blocks - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    def _():
+        _finish(o_ref, acc_ref, l_ref)
 
 
 def elite_decode_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -321,62 +317,18 @@ def elite_decode_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     per-slot quantization scales.  Output is always f32 (the int8 pages must
     never leak their dtype into the attention output).
     """
-    B, nh, r2 = q_e.shape
-    nkv = k_e_pages.shape[1]
-    d_c = c_k_pages.shape[-1]
-    G = q_group
-    assert nh == nkv * G, (nh, nkv, G)
-    assert k_e_pages.shape[0] % block_size == 0, (k_e_pages.shape, block_size)
-    n_blocks_pool = k_e_pages.shape[0] // block_size
-    mb = block_tables.shape[1]
-    assert block_tables.shape == (B, mb) and lengths.shape == (B,)
-
-    q_e_g = q_e.astype(jnp.float32).reshape(B, nkv, G, r2)
-    q_lat_g = q_lat.astype(jnp.float32).reshape(B, nkv, G, d_c)
-    k_e_p = k_e_pages.reshape(n_blocks_pool, block_size, nkv, r2)
-    c_k_p = c_k_pages.reshape(n_blocks_pool, block_size, d_c)
-    c_v_p = c_v_pages.reshape(n_blocks_pool, block_size, d_c)
-    k_s_p = k_e_scale.reshape(n_blocks_pool, block_size)
-    ck_s_p = c_k_scale.reshape(n_blocks_pool, block_size)
-    cv_s_p = c_v_scale.reshape(n_blocks_pool, block_size)
-
-    out = pl.pallas_call(
+    nkv = _check_heads(q_e, k_e_pages, q_group)
+    B, mb = block_tables.shape
+    assert q_e.shape[0] == B and lengths.shape == (B,)
+    return _paged_call(
         functools.partial(_paged_kernel_q8, block_size=block_size,
                           scale=scale, max_blocks=mb),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, nkv, mb),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, r2), lambda b, h, s, bt, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, bt, L: (b, h, 0, 0)),
-                # int8 pool pages + their per-slot scales, all indexed through
-                # the same prefetched block table (one walk, two reads/page)
-                pl.BlockSpec((1, block_size, 1, r2),
-                             lambda b, h, s, bt, L: (bt[b, s], 0, h, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, bt, L: (bt[b, s], 0, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, bt, L: (bt[b, s], 0, 0)),
-                pl.BlockSpec((1, block_size),
-                             lambda b, h, s, bt, L: (bt[b, s], 0)),
-                pl.BlockSpec((1, block_size),
-                             lambda b, h, s, bt, L: (bt[b, s], 0)),
-                pl.BlockSpec((1, block_size),
-                             lambda b, h, s, bt, L: (bt[b, s], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, bt, L: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, d_c), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, nkv, G, d_c), jnp.float32),
-        interpret=interpret,
-        name="elite_decode_paged_q8",
-    )(block_tables, lengths, q_e_g, q_lat_g, k_e_p, c_k_p, c_v_p,
-      k_s_p, ck_s_p, cv_s_p)
-    return out.reshape(B, nh, d_c)
+        "elite_decode_paged_q8", (block_tables, lengths),
+        _block_diag_q_e(q_e.astype(jnp.float32), nkv),
+        q_lat.astype(jnp.float32),
+        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages,
+                    k_e_scale, c_k_scale, c_v_scale),
+        jnp.float32, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -389,7 +341,7 @@ def _sparse_kernel(sel_tables_ref,            # scalar-prefetch [B, W] int32
                    o_ref,
                    acc_ref, m_ref, l_ref,
                    *, scale: float, num_sel: int):
-    """``_paged_kernel`` where grid dim 2 walks ``sel_tables`` — the top-k
+    """``_paged_kernel`` where grid dim 1 walks ``sel_tables`` — the top-k
     block selection from ``ref.py::select_topk_blocks`` — instead of the full
     block chain.  The length mask becomes a per-block row count
     (``sel_counts[b, sb]``; 0 skips the block entirely), so the kernel does
@@ -397,47 +349,24 @@ def _sparse_kernel(sel_tables_ref,            # scalar-prefetch [B, W] int32
     order; with the full chain selected the walk, mask, and accumulation
     order equal the dense kernel's exactly (the bit-identity wall)."""
     b = pl.program_id(0)
-    sb = pl.program_id(2)
+    sb = pl.program_id(1)
 
     @pl.when(sb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
     count = sel_counts_ref[b, sb]
 
     @pl.when(count > 0)
     def _step():
-        q_e = q_e_ref[0, 0]                           # [G, 2r]
-        q_lat = q_lat_ref[0, 0]                       # [G, d_c]
-        k_e = k_e_ref[0, :, 0, :]                     # [block_size, 2r]
-        c_k = c_k_ref[0]                              # [block_size, d_c]
-        s = jax.lax.dot_general(
-            q_e, k_e, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, block_size]
-        s += jax.lax.dot_general(
-            q_lat, c_k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s *= scale
+        s = _scores(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, scale)
         off = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(off < count, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(c_v_ref.dtype), c_v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, d_c]
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        _accumulate(jnp.where(off < count, s, NEG_INF), c_v_ref[0],
+                    acc_ref, m_ref, l_ref)
 
     @pl.when(sb == num_sel - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    def _():
+        _finish(o_ref, acc_ref, l_ref)
 
 
 def elite_decode_sparse_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -451,50 +380,15 @@ def elite_decode_sparse_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     (0 ⇒ skip; all-0 lanes produce zeros) come from
     ``ref.py::select_topk_blocks``.  →  o [B,nh,d_c].
     """
-    B, nh, r2 = q_e.shape
-    nkv = k_e_pages.shape[1]
-    d_c = c_k_pages.shape[-1]
-    G = q_group
-    assert nh == nkv * G, (nh, nkv, G)
-    assert k_e_pages.shape[0] % block_size == 0, (k_e_pages.shape, block_size)
-    n_blocks_pool = k_e_pages.shape[0] // block_size
-    W = sel_tables.shape[1]
-    assert sel_tables.shape == (B, W) and sel_counts.shape == (B, W)
-
-    q_e_g = q_e.reshape(B, nkv, G, r2)
-    q_lat_g = q_lat.reshape(B, nkv, G, d_c)
-    k_e_p = k_e_pages.reshape(n_blocks_pool, block_size, nkv, r2)
-    c_k_p = c_k_pages.reshape(n_blocks_pool, block_size, d_c)
-    c_v_p = c_v_pages.reshape(n_blocks_pool, block_size, d_c)
-
-    out = pl.pallas_call(
+    nkv = _check_heads(q_e, k_e_pages, q_group)
+    B, W = sel_tables.shape
+    assert q_e.shape[0] == B and sel_counts.shape == (B, W)
+    return _paged_call(
         functools.partial(_sparse_kernel, scale=scale, num_sel=W),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, nkv, W),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, r2), lambda b, h, s, st, ct: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, st, ct: (b, h, 0, 0)),
-                # pool pages, indexed through the prefetched SELECTION table
-                pl.BlockSpec((1, block_size, 1, r2),
-                             lambda b, h, s, st, ct: (st[b, s], 0, h, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, st, ct: (st[b, s], 0, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, st, ct: (st[b, s], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, st, ct: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, d_c), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, nkv, G, d_c), c_v_pages.dtype),
-        interpret=interpret,
-        name="elite_decode_sparse_paged",
-    )(sel_tables, sel_counts, q_e_g, q_lat_g, k_e_p, c_k_p, c_v_p)
-    return out.reshape(B, nh, d_c)
+        "elite_decode_sparse_paged", (sel_tables, sel_counts),
+        _block_diag_q_e(q_e, nkv), q_lat,
+        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages),
+        c_v_pages.dtype, interpret)
 
 
 def _sparse_kernel_q8(sel_tables_ref,         # scalar-prefetch [B, W] int32
@@ -505,55 +399,28 @@ def _sparse_kernel_q8(sel_tables_ref,         # scalar-prefetch [B, W] int32
                       acc_ref, m_ref, l_ref,
                       *, scale: float, num_sel: int):
     """``_sparse_kernel`` over int8 pages: the selection walk also pulls each
-    page's per-slot f32 scales and dequantizes in-register, exactly like
-    ``_paged_kernel_q8``."""
+    block's per-slot f32 scales, exactly like ``_paged_kernel_q8``."""
     b = pl.program_id(0)
-    sb = pl.program_id(2)
+    sb = pl.program_id(1)
 
     @pl.when(sb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
     count = sel_counts_ref[b, sb]
 
     @pl.when(count > 0)
     def _step():
-        q_e = q_e_ref[0, 0]                           # [G, 2r]
-        q_lat = q_lat_ref[0, 0]                       # [G, d_c]
-        k_s = k_s_ref[0]                              # [block_size]
-        ck_s = ck_s_ref[0]
-        k_e = k_e_ref[0, :, 0, :].astype(jnp.float32) \
-            * k_s[:, None]                            # [block_size, 2r]
-        c_k = c_k_ref[0].astype(jnp.float32) \
-            * ck_s[:, None]                           # [block_size, d_c]
-        s = jax.lax.dot_general(
-            q_e, k_e, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, block_size]
-        s += jax.lax.dot_general(
-            q_lat, c_k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s *= scale
+        s = _scores_q8(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, k_s_ref,
+                       ck_s_ref, scale)
         off = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(off < count, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        c_v = c_v_ref[0].astype(jnp.float32) * cv_s_ref[0][:, None]
-        pv = jax.lax.dot_general(
-            p, c_v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [G, d_c]
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        _accumulate(jnp.where(off < count, s, NEG_INF),
+                    c_v_ref[0].astype(jnp.float32), acc_ref, m_ref, l_ref,
+                    cv_s_ref[0])
 
     @pl.when(sb == num_sel - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    def _():
+        _finish(o_ref, acc_ref, l_ref)
 
 
 def elite_decode_sparse_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -566,59 +433,17 @@ def elite_decode_sparse_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     ``elite_decode_sparse_paged`` over int8 pages + per-slot f32 scales;
     output is always f32.
     """
-    B, nh, r2 = q_e.shape
-    nkv = k_e_pages.shape[1]
-    d_c = c_k_pages.shape[-1]
-    G = q_group
-    assert nh == nkv * G, (nh, nkv, G)
-    assert k_e_pages.shape[0] % block_size == 0, (k_e_pages.shape, block_size)
-    n_blocks_pool = k_e_pages.shape[0] // block_size
-    W = sel_tables.shape[1]
-    assert sel_tables.shape == (B, W) and sel_counts.shape == (B, W)
-
-    q_e_g = q_e.astype(jnp.float32).reshape(B, nkv, G, r2)
-    q_lat_g = q_lat.astype(jnp.float32).reshape(B, nkv, G, d_c)
-    k_e_p = k_e_pages.reshape(n_blocks_pool, block_size, nkv, r2)
-    c_k_p = c_k_pages.reshape(n_blocks_pool, block_size, d_c)
-    c_v_p = c_v_pages.reshape(n_blocks_pool, block_size, d_c)
-    k_s_p = k_e_scale.reshape(n_blocks_pool, block_size)
-    ck_s_p = c_k_scale.reshape(n_blocks_pool, block_size)
-    cv_s_p = c_v_scale.reshape(n_blocks_pool, block_size)
-
-    out = pl.pallas_call(
+    nkv = _check_heads(q_e, k_e_pages, q_group)
+    B, W = sel_tables.shape
+    assert q_e.shape[0] == B and sel_counts.shape == (B, W)
+    return _paged_call(
         functools.partial(_sparse_kernel_q8, scale=scale, num_sel=W),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, nkv, W),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, r2), lambda b, h, s, st, ct: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, st, ct: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_size, 1, r2),
-                             lambda b, h, s, st, ct: (st[b, s], 0, h, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, st, ct: (st[b, s], 0, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, st, ct: (st[b, s], 0, 0)),
-                pl.BlockSpec((1, block_size),
-                             lambda b, h, s, st, ct: (st[b, s], 0)),
-                pl.BlockSpec((1, block_size),
-                             lambda b, h, s, st, ct: (st[b, s], 0)),
-                pl.BlockSpec((1, block_size),
-                             lambda b, h, s, st, ct: (st[b, s], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, d_c), lambda b, h, s, st, ct: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, d_c), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, nkv, G, d_c), jnp.float32),
-        interpret=interpret,
-        name="elite_decode_sparse_paged_q8",
-    )(sel_tables, sel_counts, q_e_g, q_lat_g, k_e_p, c_k_p, c_v_p,
-      k_s_p, ck_s_p, cv_s_p)
-    return out.reshape(B, nh, d_c)
+        "elite_decode_sparse_paged_q8", (sel_tables, sel_counts),
+        _block_diag_q_e(q_e.astype(jnp.float32), nkv),
+        q_lat.astype(jnp.float32),
+        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages,
+                    k_e_scale, c_k_scale, c_v_scale),
+        jnp.float32, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -632,21 +457,19 @@ def _verify_kernel(block_tables_ref,          # scalar-prefetch [B, mb] int32
                    o_ref,
                    acc_ref, m_ref, l_ref,
                    *, block_size: int, scale: float, max_blocks: int,
-                   q_group: int):
-    """``_paged_kernel`` generalized to ``window · G`` query rows per
-    (batch, kv-head): row ``r`` holds window position ``w = r // G`` whose
-    global query position is ``q_offsets[b] + w``, so the length mask gains
-    the per-row offset-causal term of ``flash_prefill``'s diagonal —
-    speculative verify scores all ``k+1`` window tokens in one block-table
-    walk over the compressed cache."""
+                   n_heads: int):
+    """``_paged_kernel`` generalized to ``window · nh`` query rows per lane:
+    row ``r`` holds window position ``w = r // nh`` whose global query
+    position is ``q_offsets[b] + w``, so the length mask gains the per-row
+    offset-causal term of ``flash_prefill``'s diagonal — speculative verify
+    scores all ``k+1`` window tokens in one block-table walk over the
+    compressed cache."""
     b = pl.program_id(0)
-    sb = pl.program_id(2)
+    sb = pl.program_id(1)
 
     @pl.when(sb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
     length = lengths_ref[b]
     q_offset = q_offsets_ref[b]
@@ -654,36 +477,24 @@ def _verify_kernel(block_tables_ref,          # scalar-prefetch [B, mb] int32
 
     @pl.when(start < length)
     def _step():
-        q_e = q_e_ref[0, 0]                           # [W·G, 2r]
-        q_lat = q_lat_ref[0, 0]                       # [W·G, d_c]
-        k_e = k_e_ref[0, :, 0, :]                     # [block_size, 2r]
-        c_k = c_k_ref[0]                              # [block_size, d_c]
-        s = jax.lax.dot_general(
-            q_e, k_e, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [W·G, block_size]
-        s += jax.lax.dot_general(
-            q_lat, c_k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s *= scale
+        s = _scores(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, scale)
         pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qw = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // q_group
-        s = jnp.where((pos <= q_offset + qw) & (pos < length), s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(c_v_ref.dtype), c_v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [W·G, d_c]
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        qw = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // n_heads
+        _accumulate(jnp.where((pos <= q_offset + qw) & (pos < length), s,
+                              NEG_INF),
+                    c_v_ref[0], acc_ref, m_ref, l_ref)
 
     @pl.when(sb == max_blocks - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    def _():
+        _finish(o_ref, acc_ref, l_ref)
+
+
+def _window_rows(q_e, q_lat, nkv: int):
+    """[B, W, nh, *] window queries → kernel rows [B, W·nh, *] in (w, n)
+    order, with the rotary part block-diagonal."""
+    B, W, nh, _ = q_e.shape
+    return (_block_diag_q_e(q_e, nkv).reshape(B, W * nh, -1),
+            q_lat.reshape(B, W * nh, -1))
 
 
 def elite_verify_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -697,58 +508,19 @@ def elite_verify_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     lengths [B] int32 (live tokens *including* the window; 0 = dead lane)
     →  o [B,W,nh,d_c].  Length-0 lanes produce zeros.
     """
-    B, W, nh, r2 = q_e.shape
-    nkv = k_e_pages.shape[1]
-    d_c = c_k_pages.shape[-1]
-    G = q_group
-    assert nh == nkv * G, (nh, nkv, G)
-    assert k_e_pages.shape[0] % block_size == 0, (k_e_pages.shape, block_size)
-    n_blocks_pool = k_e_pages.shape[0] // block_size
+    nkv = _check_heads(q_e, k_e_pages, q_group)
+    B, W, nh, _ = q_e.shape
     mb = block_tables.shape[1]
     assert block_tables.shape == (B, mb)
     assert q_offsets.shape == (B,) and lengths.shape == (B,)
-
-    # row layout (w, g): row r of a (b, kv-head) tile is window position r // G
-    q_e_g = q_e.reshape(B, W, nkv, G, r2).transpose(0, 2, 1, 3, 4) \
-        .reshape(B, nkv, W * G, r2)
-    q_lat_g = q_lat.reshape(B, W, nkv, G, d_c).transpose(0, 2, 1, 3, 4) \
-        .reshape(B, nkv, W * G, d_c)
-    k_e_p = k_e_pages.reshape(n_blocks_pool, block_size, nkv, r2)
-    c_k_p = c_k_pages.reshape(n_blocks_pool, block_size, d_c)
-    c_v_p = c_v_pages.reshape(n_blocks_pool, block_size, d_c)
-
-    out = pl.pallas_call(
+    out = _paged_call(
         functools.partial(_verify_kernel, block_size=block_size, scale=scale,
-                          max_blocks=mb, q_group=G),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, nkv, mb),
-            in_specs=[
-                pl.BlockSpec((1, 1, W * G, r2),
-                             lambda b, h, s, bt, off, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, W * G, d_c),
-                             lambda b, h, s, bt, off, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_size, 1, r2),
-                             lambda b, h, s, bt, off, L: (bt[b, s], 0, h, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, bt, off, L: (bt[b, s], 0, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, bt, off, L: (bt[b, s], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, W * G, d_c),
-                                   lambda b, h, s, bt, off, L: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((W * G, d_c), jnp.float32),
-                pltpu.VMEM((W * G, 1), jnp.float32),
-                pltpu.VMEM((W * G, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, nkv, W * G, d_c), c_v_pages.dtype),
-        interpret=interpret,
-        name="elite_verify_paged",
-    )(block_tables, q_offsets, lengths, q_e_g, q_lat_g, k_e_p, c_k_p, c_v_p)
-    return out.reshape(B, nkv, W, G, d_c).transpose(0, 2, 1, 3, 4) \
-        .reshape(B, W, nh, d_c)
+                          max_blocks=mb, n_heads=nh),
+        "elite_verify_paged", (block_tables, q_offsets, lengths),
+        *_window_rows(q_e, q_lat, nkv),
+        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages),
+        c_v_pages.dtype, interpret)
+    return out.reshape(B, W, nh, -1)
 
 
 def _verify_kernel_q8(block_tables_ref,       # scalar-prefetch [B, mb] int32
@@ -759,18 +531,16 @@ def _verify_kernel_q8(block_tables_ref,       # scalar-prefetch [B, mb] int32
                       o_ref,
                       acc_ref, m_ref, l_ref,
                       *, block_size: int, scale: float, max_blocks: int,
-                      q_group: int):
-    """``_verify_kernel`` over int8 pages with fused in-register dequant —
-    same W·G query-row layout and offset-causal mask, same per-slot scale
-    loads as ``_paged_kernel_q8``."""
+                      n_heads: int):
+    """``_verify_kernel`` over int8 pages with fused dequant — same W·nh
+    query-row layout and offset-causal mask, same per-slot scale loads as
+    ``_paged_kernel_q8``."""
     b = pl.program_id(0)
-    sb = pl.program_id(2)
+    sb = pl.program_id(1)
 
     @pl.when(sb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
     length = lengths_ref[b]
     q_offset = q_offsets_ref[b]
@@ -778,39 +548,18 @@ def _verify_kernel_q8(block_tables_ref,       # scalar-prefetch [B, mb] int32
 
     @pl.when(start < length)
     def _step():
-        q_e = q_e_ref[0, 0]                           # [W·G, 2r]
-        q_lat = q_lat_ref[0, 0]                       # [W·G, d_c]
-        k_e = k_e_ref[0, :, 0, :].astype(jnp.float32) \
-            * k_s_ref[0][:, None]                     # [block_size, 2r]
-        c_k = c_k_ref[0].astype(jnp.float32) \
-            * ck_s_ref[0][:, None]                    # [block_size, d_c]
-        s = jax.lax.dot_general(
-            q_e, k_e, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [W·G, block_size]
-        s += jax.lax.dot_general(
-            q_lat, c_k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s *= scale
+        s = _scores_q8(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, k_s_ref,
+                       ck_s_ref, scale)
         pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qw = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // q_group
-        s = jnp.where((pos <= q_offset + qw) & (pos < length), s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        c_v = c_v_ref[0].astype(jnp.float32) * cv_s_ref[0][:, None]
-        pv = jax.lax.dot_general(
-            p, c_v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [W·G, d_c]
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        qw = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // n_heads
+        _accumulate(jnp.where((pos <= q_offset + qw) & (pos < length), s,
+                              NEG_INF),
+                    c_v_ref[0].astype(jnp.float32), acc_ref, m_ref, l_ref,
+                    cv_s_ref[0])
 
     @pl.when(sb == max_blocks - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    def _():
+        _finish(o_ref, acc_ref, l_ref)
 
 
 def elite_verify_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -823,67 +572,21 @@ def elite_verify_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     ``elite_verify_paged`` over int8 pages + per-slot f32 scales; output is
     always f32.
     """
-    B, W, nh, r2 = q_e.shape
-    nkv = k_e_pages.shape[1]
-    d_c = c_k_pages.shape[-1]
-    G = q_group
-    assert nh == nkv * G, (nh, nkv, G)
-    assert k_e_pages.shape[0] % block_size == 0, (k_e_pages.shape, block_size)
-    n_blocks_pool = k_e_pages.shape[0] // block_size
+    nkv = _check_heads(q_e, k_e_pages, q_group)
+    B, W, nh, _ = q_e.shape
     mb = block_tables.shape[1]
     assert block_tables.shape == (B, mb)
     assert q_offsets.shape == (B,) and lengths.shape == (B,)
-
-    q_e_g = q_e.astype(jnp.float32).reshape(B, W, nkv, G, r2) \
-        .transpose(0, 2, 1, 3, 4).reshape(B, nkv, W * G, r2)
-    q_lat_g = q_lat.astype(jnp.float32).reshape(B, W, nkv, G, d_c) \
-        .transpose(0, 2, 1, 3, 4).reshape(B, nkv, W * G, d_c)
-    k_e_p = k_e_pages.reshape(n_blocks_pool, block_size, nkv, r2)
-    c_k_p = c_k_pages.reshape(n_blocks_pool, block_size, d_c)
-    c_v_p = c_v_pages.reshape(n_blocks_pool, block_size, d_c)
-    k_s_p = k_e_scale.reshape(n_blocks_pool, block_size)
-    ck_s_p = c_k_scale.reshape(n_blocks_pool, block_size)
-    cv_s_p = c_v_scale.reshape(n_blocks_pool, block_size)
-
-    out = pl.pallas_call(
+    out = _paged_call(
         functools.partial(_verify_kernel_q8, block_size=block_size,
-                          scale=scale, max_blocks=mb, q_group=G),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, nkv, mb),
-            in_specs=[
-                pl.BlockSpec((1, 1, W * G, r2),
-                             lambda b, h, s, bt, off, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, W * G, d_c),
-                             lambda b, h, s, bt, off, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_size, 1, r2),
-                             lambda b, h, s, bt, off, L: (bt[b, s], 0, h, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, bt, off, L: (bt[b, s], 0, 0)),
-                pl.BlockSpec((1, block_size, d_c),
-                             lambda b, h, s, bt, off, L: (bt[b, s], 0, 0)),
-                pl.BlockSpec((1, block_size),
-                             lambda b, h, s, bt, off, L: (bt[b, s], 0)),
-                pl.BlockSpec((1, block_size),
-                             lambda b, h, s, bt, off, L: (bt[b, s], 0)),
-                pl.BlockSpec((1, block_size),
-                             lambda b, h, s, bt, off, L: (bt[b, s], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, W * G, d_c),
-                                   lambda b, h, s, bt, off, L: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((W * G, d_c), jnp.float32),
-                pltpu.VMEM((W * G, 1), jnp.float32),
-                pltpu.VMEM((W * G, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, nkv, W * G, d_c), jnp.float32),
-        interpret=interpret,
-        name="elite_verify_paged_q8",
-    )(block_tables, q_offsets, lengths, q_e_g, q_lat_g, k_e_p, c_k_p, c_v_p,
-      k_s_p, ck_s_p, cv_s_p)
-    return out.reshape(B, nkv, W, G, d_c).transpose(0, 2, 1, 3, 4) \
-        .reshape(B, W, nh, d_c)
+                          scale=scale, max_blocks=mb, n_heads=nh),
+        "elite_verify_paged_q8", (block_tables, q_offsets, lengths),
+        *_window_rows(q_e.astype(jnp.float32), q_lat.astype(jnp.float32),
+                      nkv),
+        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages,
+                    k_e_scale, c_k_scale, c_v_scale),
+        jnp.float32, interpret)
+    return out.reshape(B, W, nh, -1)
 
 
 def elite_verify_paged_xla(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,

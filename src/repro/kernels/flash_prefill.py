@@ -7,7 +7,9 @@ blocks strictly above the diagonal are never touched, halving HBM traffic.
 
 GQA is handled by mapping each q-head to its kv head in the BlockSpec index
 map (no materialized K/V repeat — the repeat would multiply HBM reads by the
-group size).
+group size).  The kernel runs on head-major views ([B, heads, S, dh]) so each
+block is a [rows, dh] tile of one head: the TPU lowering refuses a block of 1
+on the head axis when that axis is second-minor ([B, S, heads, dh]).
 
 Used at prefill for EliteKV models *after* the latent up-projection
 materializes K = [K_e | c·bk] and V = c·bv for the current chunk; training
@@ -69,9 +71,9 @@ def _kernel(q_offsets_ref, kv_lens_ref,     # scalar-prefetch [B] int32 each
 
     @pl.when(visible)
     def _step():
-        q = q_ref[0, :, 0, :]                                # [bq, dh]
-        k = k_ref[0, :, 0, :]                                # [bk, dh]
-        v = v_ref[0, :, 0, :]                                # [bk, dh]
+        q = q_ref[0, 0]                                      # [bq, dh]
+        k = k_ref[0, 0]                                      # [bk, dh]
+        v = v_ref[0, 0]                                      # [bk, dh]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -90,7 +92,7 @@ def _kernel(q_offsets_ref, kv_lens_ref,     # scalar-prefetch [B] int32 each
 
     @pl.when(jk == n_kb - 1)
     def _finish():
-        o_ref[0, :, 0, :] = (
+        o_ref[0, 0] = (
             acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
@@ -123,6 +125,7 @@ def flash_prefill(q, k, v, q_group: int, scale: float,
     assert Sq % block_q == 0 and Sk % block_k == 0, (Sq, Sk, block_q, block_k)
     n_qb, n_kb = Sq // block_q, Sk // block_k
 
+    heads_major = functools.partial(jnp.swapaxes, axis1=1, axis2=2)
     out = pl.pallas_call(
         functools.partial(_kernel, block_q=block_q, block_k=block_k,
                           scale=scale, n_kb=n_kb),
@@ -130,23 +133,23 @@ def flash_prefill(q, k, v, q_group: int, scale: float,
             num_scalar_prefetch=2,
             grid=(B, nh, n_qb, n_kb),
             in_specs=[
-                pl.BlockSpec((1, block_q, 1, dh),
-                             lambda b, h, i, j, off, kl: (b, i, h, 0)),
-                pl.BlockSpec((1, block_k, 1, dh),
-                             lambda b, h, i, j, off, kl, g=q_group: (b, j, h // g, 0)),
-                pl.BlockSpec((1, block_k, 1, dh),
-                             lambda b, h, i, j, off, kl, g=q_group: (b, j, h // g, 0)),
+                pl.BlockSpec((1, 1, block_q, dh),
+                             lambda b, h, i, j, off, kl: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, block_k, dh),
+                             lambda b, h, i, j, off, kl, g=q_group: (b, h // g, j, 0)),
+                pl.BlockSpec((1, 1, block_k, dh),
+                             lambda b, h, i, j, off, kl, g=q_group: (b, h // g, j, 0)),
             ],
-            out_specs=pl.BlockSpec((1, block_q, 1, dh),
-                                   lambda b, h, i, j, off, kl: (b, i, h, 0)),
+            out_specs=pl.BlockSpec((1, 1, block_q, dh),
+                                   lambda b, h, i, j, off, kl: (b, h, i, 0)),
             scratch_shapes=[
                 pltpu.VMEM((block_q, dh), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, nh, dh), v.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nh, Sq, dh), v.dtype),
         interpret=interpret,
         name="flash_prefill",
-    )(q_offsets, kv_lens, q, k, v)
-    return out
+    )(q_offsets, kv_lens, heads_major(q), heads_major(k), heads_major(v))
+    return heads_major(out)

@@ -1,8 +1,15 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute via ``interpret=True`` — the
-kernel body runs in Python per grid step, numerically identical to the TPU
-lowering.  On TPU backends they compile through Mosaic.
+``_interpret()`` picks the path from the platform.  On a TPU the kernels
+compile through Mosaic; elsewhere the paged wrappers take their XLA gather
+fallback and the others run with ``interpret=True`` (the kernel body runs in
+Python per grid step).  Lowered and run on a TPU v5e (``chip_smoke.py``,
+within its stated tolerance of ``kernels/ref.py``): ``elite_decode_paged``,
+``elite_decode_paged_q8``, ``elite_decode_sparse_paged``,
+``elite_decode_sparse_paged_q8``, ``elite_verify_paged`` and
+``elite_verify_paged_q8``.  ``elite_decode``, ``flash_prefill`` and
+``rope_elite`` compile for a described v5e (``tests/test_tpu_compile.py``)
+but have not run on one.
 
 Observability (docs/observability.md): ``set_kernel_tracer`` arms opt-in
 host-side spans around the public dispatches — each call is timed
@@ -388,21 +395,11 @@ from jax.sharding import PartitionSpec as _P
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-compat ``shard_map``: ``jax.shard_map`` where it exists, the
-    ``jax.experimental`` spelling otherwise, with replication checking
-    disabled under whichever keyword this jax spells it — the epilogue
+    """``jax.shard_map`` with varying-manual-axes checking off: the epilogue
     all_gather makes outputs replicated by construction, which the static
     checker cannot see through the inner jit call."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible shard_map signature found")
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _rep(x) -> _P:

@@ -1,5 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Dry-run profiler: top live buffers + per-collective breakdown for a cell.
 
 The "profile" available without hardware is the partitioned HLO — this tool
@@ -168,6 +166,8 @@ def main(argv=None):
     ap.add_argument("--top", type=int, default=15)
     args = ap.parse_args(argv)
 
+    # importing dryrun sets its placeholder-device XLA_FLAGS (only this
+    # subcommand needs them; trace-summary leaves the environment alone)
     from repro.launch import dryrun
     res, compiled, cfg = dryrun.lower_cell(
         args.arch, args.shape, args.multi_pod, elitekv=not args.no_elitekv,

@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # ^ MUST precede any jax import: the dry-run builds the 256/512-chip
-# production mesh out of placeholder host devices (this file only — smoke
-# tests and benches see the real single CPU device).
+# production mesh out of placeholder CPU host devices (this file only —
+# smoke tests and benches see the real single CPU device), even on a host
+# that has an accelerator.
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this driver builds the full sharded step function

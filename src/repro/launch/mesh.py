@@ -9,20 +9,15 @@ import jax
 import numpy as np
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwarg for ``jax.make_mesh``, or ``{}`` on jax versions
-    that predate ``jax.sharding.AxisType`` (e.g. 0.4.x)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; multi_pod adds the 2-pod "pod" axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_debug_mesh(shape=None, axes=("data", "model")):
@@ -37,7 +32,7 @@ def make_debug_mesh(shape=None, axes=("data", "model")):
         n = jax.device_count()
         shape = (n // 2, 2) if n >= 2 else (1,) * len(axes)
         shape = shape[: len(axes)] + (1,) * (len(axes) - len(shape))
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_serving_mesh(tp: int = 1, dp: int = 1):

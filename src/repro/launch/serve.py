@@ -79,6 +79,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.cache import model_cache_floats_per_token
 from repro.core.convert import pick_dims
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.obs import REGISTRY, Tracer, write_chrome_trace
 from repro.runtime import serve_loop
@@ -86,7 +87,8 @@ from repro.runtime import serve_loop
 
 def serve_stream(params, buffers, cfg, args):
     """Poisson request-stream mode: exercises admission, mid-flight prefill,
-    retirement and block recycling; prints the scheduler metrics."""
+    retirement and block recycling; prints the scheduler metrics.
+    → (report, the ``Scheduler`` or, with ``--dp`` > 1, the ``Router``)."""
     rng = np.random.default_rng(args.seed)
     tracer = Tracer(capacity=args.trace_capacity) if args.trace else None
     if tracer is not None:
@@ -156,7 +158,7 @@ def serve_stream(params, buffers, cfg, args):
                 f.write(REGISTRY.to_prometheus())
             print(f"metrics: {len(REGISTRY.names())} instruments -> "
                   f"{args.metrics_out} (Prometheus text format)")
-        return rep
+        return rep, router
     report = sched.run(reqs)
     stats = sched.pool.stats()
     tptag = f" [tp={args.tp}]" if args.tp > 1 else ""
@@ -220,7 +222,7 @@ def serve_stream(params, buffers, cfg, args):
             f.write(REGISTRY.to_prometheus())
         print(f"metrics: {len(REGISTRY.names())} instruments -> "
               f"{args.metrics_out} (Prometheus text format)")
-    return report
+    return report, sched
 
 
 def main(argv=None):
@@ -373,4 +375,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

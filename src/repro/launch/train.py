@@ -21,6 +21,7 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import get_config
 from repro.core.convert import pick_dims
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.optim import schedule as sched_lib
 from repro.optim.adamw import AdamWConfig
@@ -91,4 +92,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

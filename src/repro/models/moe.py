@@ -200,15 +200,14 @@ def apply_ep(params, cfg, x, mesh, data_axes=("data",), model_axis="model",
                           "w_up": P(None, model_axis),
                           "w_down": P(model_axis, None)}
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p, xx: _ep_local(p, cfg, xx, E_loc, capacity, model_axis,
                                 fsdp_axes=fsdp_axes,
                                 data_axes=tuple(data_axes) if bshard else ()),
         mesh=mesh,
         in_specs=(wspec, P(dspec, None, None)),
         out_specs=(P(dspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     if remat:
         # §Perf iteration: jax.checkpoint does NOT see through shard_map from

@@ -563,6 +563,13 @@ class Scheduler:
             ("partial-width sparse decode requires eviction='swap' (or "
              "admission='watermark'): recompute prefill cannot reproduce "
              "sparse-generated streams")
+        if mesh is not None:
+            # weights live on this replica's own devices (replicated over its
+            # TP axis); left on the default device, every step would copy
+            # them to the devices that hold the pool
+            params, buffers = jax.device_put(
+                (params, buffers), jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec()))
         self.params, self.buffers, self.cfg, self.scfg = params, buffers, cfg, scfg
         self.trace = tracer or NULL_TRACER
         self.metrics = metrics or MetricsRegistry()

@@ -384,6 +384,40 @@ def test_elite_verify_paged_q8_w1_equals_decode():
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("quantized", [False, True])
+def test_elite_decode_sparse_paged_kernel_vs_oracle(quantized):
+    """The sparse kernels' selection walk vs the sparse oracles: a top-k
+    selection with partial blocks and a dead lane; and with the whole chain
+    selected, the sparse kernel equals the dense paged kernel bit for bit."""
+    B, nkv, G, r2, dc, bs = 2, 2, 2, 4, 16, 4
+    q_e, q_lat, k_e_p, c_p, bt = _verify_inputs(B, 1, nkv, G, r2, dc,
+                                                n_blocks=16, bs=bs, seed=17)
+    q_e, q_lat = q_e[:, 0], q_lat[:, 0]
+    pages = (k_e_p, c_p, c_p)
+    if quantized:
+        pages = _quantize_pool(k_e_p, c_p, c_p)
+    sfx = "_q8" if quantized else ""
+    kernel = getattr(ed, "elite_decode_sparse_paged" + sfx)
+    oracle = getattr(ref, "elite_decode_sparse_paged" + sfx + "_ref")
+    lens = jnp.asarray([27, 0], jnp.int32)
+    blk = c_p.reshape(-1, bs, dc)
+    sel = ref.select_topk_blocks(q_lat, blk.mean(1), jnp.abs(blk).max(1), bt,
+                                 lens, bs, num_sel=3, recent=1)
+    o_k = kernel(q_e, q_lat, *pages, *sel, G, 0.2, bs, interpret=True)
+    o_r = oracle(q_e, q_lat, *pages, *sel, G, 0.2, bs)
+    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
+                               atol=3e-5, rtol=3e-5)
+    assert float(jnp.max(jnp.abs(o_k[1]))) == 0.0
+    full = ref.select_topk_blocks(q_lat, blk.mean(1), jnp.abs(blk).max(1), bt,
+                                  lens, bs, num_sel=bt.shape[1], recent=1)
+    dense = getattr(ed, "elite_decode_paged" + sfx)
+    np.testing.assert_array_equal(
+        np.asarray(kernel(q_e, q_lat, *pages, *full, G, 0.2, bs,
+                          interpret=True)),
+        np.asarray(dense(q_e, q_lat, *pages, bt, lens, G, 0.2, bs,
+                         interpret=True)))
+
+
 def test_q8_oracle_tracks_f32_oracle():
     """Quality sanity at the kernel level: the quantized oracle's outputs
     stay close to the f32 oracle over the same pool (int8 absmax keeps
