@@ -1,0 +1,2 @@
+"""device.idle_share.open: see ``servbench.readers.idle_share``."""
+from servbench.readers import idle_share as read  # noqa: F401

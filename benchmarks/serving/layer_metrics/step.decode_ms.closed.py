@@ -1,0 +1,2 @@
+"""step.decode_ms.closed: see ``servbench.readers.decode_ms``."""
+from servbench.readers import decode_ms as read  # noqa: F401
