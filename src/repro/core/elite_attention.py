@@ -240,6 +240,7 @@ def apply_decode(params, cfg, buffers, x, index, cache, use_kernel: bool = False
 # paged variants — the cache lives in a shared block pool (serving runtime)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("pool_write")
 def _scatter_pages(pages, k_e_new, c_k_new, c_v_new, slot_mapping):
     """Write per-token compressed streams into pool pages at flat slots.
     Out-of-range slots (the inactive-lane / prompt-padding sentinel) are

@@ -10,17 +10,6 @@ within its stated tolerance of ``kernels/ref.py``): ``elite_decode_paged``,
 ``elite_verify_paged_q8``.  ``elite_decode``, ``flash_prefill`` and
 ``rope_elite`` compile for a described v5e (``tests/test_tpu_compile.py``)
 but have not run on one.
-
-Observability (docs/observability.md): ``set_kernel_tracer`` arms opt-in
-host-side spans around the public dispatches — each call is timed
-``block_until_ready`` so the span covers the device work, and lands on the
-``kernel`` track of the trace timeline.  Spans fire only on the *eager* path
-(micro-benchmarks, oracle comparisons, direct calls): when a wrapper runs
-inside an outer ``jax.jit`` trace its arguments are abstract ``Tracer``
-values, the dispatch happens later inside XLA, and host-side timing would be
-meaningless — those calls are detected and skipped.  Timing never changes
-results (the same jitted computation runs either way), so traced and
-untraced runs stay bit-identical.
 """
 from __future__ import annotations
 
@@ -32,29 +21,6 @@ import jax.numpy as jnp
 from repro.kernels import elite_decode as _ed
 from repro.kernels import flash_prefill as _fp
 from repro.kernels import rope_elite as _re
-
-_TRACER = None                               # module-level opt-in (obs.Tracer)
-
-
-def set_kernel_tracer(tracer) -> None:
-    """Install (or clear with ``None``) the tracer kernel dispatches report
-    to.  Process-wide by design: kernel call sites sit below the scheduler
-    and the benchmark harness, which should not thread a tracer through
-    every signature."""
-    global _TRACER
-    _TRACER = tracer
-
-
-def _span(name: str, *tensors):
-    """Active kernel span, or None when tracing is off / the call is being
-    traced by an outer jit (abstract arguments)."""
-    if _TRACER is None or not _TRACER.enabled:
-        return None
-    if any(isinstance(t, jax.core.Tracer) for t in tensors):
-        return None
-    return _TRACER.span(name, track="kernel", cat="kernel",
-                        shape=str(tuple(tensors[0].shape)))
-
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -69,13 +35,8 @@ def _elite_decode_jit(q_e, q_lat, k_e, c_k, c_v, lengths, q_group: int,
 
 def elite_decode(q_e, q_lat, k_e, c_k, c_v, lengths, q_group: int,
                  scale: float, block_s: int = 512):
-    sp = _span("elite_decode", q_e)
-    if sp is None:
-        return _elite_decode_jit(q_e, q_lat, k_e, c_k, c_v, lengths, q_group,
-                                 scale, block_s)
-    with sp:
-        return jax.block_until_ready(_elite_decode_jit(
-            q_e, q_lat, k_e, c_k, c_v, lengths, q_group, scale, block_s))
+    return _elite_decode_jit(q_e, q_lat, k_e, c_k, c_v, lengths, q_group,
+                             scale, block_s)
 
 
 @functools.partial(jax.jit,
@@ -101,15 +62,9 @@ def elite_decode_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     CPU / ``force_xla``: gather-based XLA fallback with identical semantics —
     interpret-mode Pallas loops the grid in Python, far too slow to serve with.
     """
-    sp = _span("elite_decode_paged", q_e)
-    if sp is None:
-        return _elite_decode_paged_jit(q_e, q_lat, k_e_pages, c_k_pages,
-                                       c_v_pages, block_tables, lengths,
-                                       q_group, scale, block_size, force_xla)
-    with sp:
-        return jax.block_until_ready(_elite_decode_paged_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, block_tables,
-            lengths, q_group, scale, block_size, force_xla))
+    return _elite_decode_paged_jit(q_e, q_lat, k_e_pages, c_k_pages,
+                                   c_v_pages, block_tables, lengths,
+                                   q_group, scale, block_size, force_xla)
 
 
 @functools.partial(jax.jit,
@@ -141,17 +96,10 @@ def elite_decode_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     TPU: fused Pallas kernel.  CPU / ``force_xla``: dequantize-then-gather
     XLA fallback with identical semantics.
     """
-    sp = _span("elite_decode_paged_q8", q_e)
-    if sp is None:
-        return _elite_decode_paged_q8_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, k_e_scale,
-            c_k_scale, c_v_scale, block_tables, lengths, q_group, scale,
-            block_size, force_xla)
-    with sp:
-        return jax.block_until_ready(_elite_decode_paged_q8_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, k_e_scale,
-            c_k_scale, c_v_scale, block_tables, lengths, q_group, scale,
-            block_size, force_xla))
+    return _elite_decode_paged_q8_jit(
+        q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, k_e_scale,
+        c_k_scale, c_v_scale, block_tables, lengths, q_group, scale,
+        block_size, force_xla)
 
 
 @functools.partial(jax.jit,
@@ -194,15 +142,9 @@ def elite_decode_sparse_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     TPU: Pallas kernel walking the prefetched selection table.
     CPU / ``force_xla``: gather-based XLA fallback with identical semantics.
     """
-    sp = _span("elite_decode_sparse_paged", q_e)
-    if sp is None:
-        return _elite_decode_sparse_paged_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, sel_tables,
-            sel_counts, q_group, scale, block_size, force_xla)
-    with sp:
-        return jax.block_until_ready(_elite_decode_sparse_paged_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, sel_tables,
-            sel_counts, q_group, scale, block_size, force_xla))
+    return _elite_decode_sparse_paged_jit(
+        q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, sel_tables,
+        sel_counts, q_group, scale, block_size, force_xla)
 
 
 @functools.partial(jax.jit,
@@ -230,17 +172,10 @@ def elite_decode_sparse_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
                                  force_xla: bool = False):
     """``elite_decode_sparse_paged`` over an int8 pool with fused in-register
     dequant; output is f32 regardless of page dtype."""
-    sp = _span("elite_decode_sparse_paged_q8", q_e)
-    if sp is None:
-        return _elite_decode_sparse_paged_q8_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, k_e_scale,
-            c_k_scale, c_v_scale, sel_tables, sel_counts, q_group, scale,
-            block_size, force_xla)
-    with sp:
-        return jax.block_until_ready(_elite_decode_sparse_paged_q8_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, k_e_scale,
-            c_k_scale, c_v_scale, sel_tables, sel_counts, q_group, scale,
-            block_size, force_xla))
+    return _elite_decode_sparse_paged_q8_jit(
+        q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, k_e_scale,
+        c_k_scale, c_v_scale, sel_tables, sel_counts, q_group, scale,
+        block_size, force_xla)
 
 
 @functools.partial(jax.jit,
@@ -272,16 +207,10 @@ def elite_verify_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     TPU: Pallas kernel walking the prefetched block table (zero gather).
     CPU / ``force_xla``: gather-based XLA fallback with identical semantics.
     """
-    sp = _span("elite_verify_paged", q_e)
-    if sp is None:
-        return _elite_verify_paged_jit(q_e, q_lat, k_e_pages, c_k_pages,
-                                       c_v_pages, block_tables, q_offsets,
-                                       lengths, q_group, scale, block_size,
-                                       force_xla)
-    with sp:
-        return jax.block_until_ready(_elite_verify_paged_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, block_tables,
-            q_offsets, lengths, q_group, scale, block_size, force_xla))
+    return _elite_verify_paged_jit(q_e, q_lat, k_e_pages, c_k_pages,
+                                   c_v_pages, block_tables, q_offsets,
+                                   lengths, q_group, scale, block_size,
+                                   force_xla)
 
 
 @functools.partial(jax.jit,
@@ -310,17 +239,10 @@ def elite_verify_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     """``elite_verify_paged`` over an int8 pool with fused in-register
     dequant — the speculative verify analogue of ``elite_decode_paged_q8``;
     output is f32 regardless of page dtype."""
-    sp = _span("elite_verify_paged_q8", q_e)
-    if sp is None:
-        return _elite_verify_paged_q8_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, k_e_scale,
-            c_k_scale, c_v_scale, block_tables, q_offsets, lengths, q_group,
-            scale, block_size, force_xla)
-    with sp:
-        return jax.block_until_ready(_elite_verify_paged_q8_jit(
-            q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, k_e_scale,
-            c_k_scale, c_v_scale, block_tables, q_offsets, lengths, q_group,
-            scale, block_size, force_xla))
+    return _elite_verify_paged_q8_jit(
+        q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, k_e_scale,
+        c_k_scale, c_v_scale, block_tables, q_offsets, lengths, q_group,
+        scale, block_size, force_xla)
 
 
 @functools.partial(jax.jit, static_argnames=("q_group", "scale", "block_q",
@@ -349,13 +271,8 @@ def flash_prefill(q, k, v, q_group: int, scale: float,
     kv_lens = (jnp.full((B,), Sk, jnp.int32) if kv_lens is None
                else jnp.asarray(kv_lens, jnp.int32))
     bq, bk = min(block_q, q.shape[1]), min(block_k, Sk)
-    sp = _span("flash_prefill", q)
-    if sp is None:
-        return _flash_prefill_jit(q, k, v, q_offsets, kv_lens, q_group, scale,
-                                  bq, bk)
-    with sp:
-        return jax.block_until_ready(_flash_prefill_jit(
-            q, k, v, q_offsets, kv_lens, q_group, scale, bq, bk))
+    return _flash_prefill_jit(q, k, v, q_offsets, kv_lens, q_group, scale,
+                              bq, bk)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s",))
@@ -365,12 +282,7 @@ def _rope_elite_jit(x, positions, freqs, block_s: int = 1024):
 
 
 def rope_elite(x, positions, freqs, block_s: int = 1024):
-    sp = _span("rope_elite", x)
-    if sp is None:
-        return _rope_elite_jit(x, positions, freqs, block_s)
-    with sp:
-        return jax.block_until_ready(_rope_elite_jit(x, positions, freqs,
-                                                     block_s))
+    return _rope_elite_jit(x, positions, freqs, block_s)
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,9 @@ written by ``launch/serve.py --trace``:
 
   python -m repro.launch.diagnose trace-summary trace.json [--top 8]
 
-prints the phase-time table, kernel-span totals, the per-request lifecycle
-table (TTFT / residency / retirement reason), the most-preempted requests,
+prints the phase-time table (self time per phase, the step's own
+remainder as ``other``), swap traffic, the per-request lifecycle table
+(TTFT / residency / retirement reason), the most-preempted requests,
 and an ASCII pool-occupancy timeline — the terminal view of what Perfetto
 renders graphically.  Traces from a data-parallel run (``--dp N``) add a
 per-replica occupancy sparkline block (from the router's ``r{i}_``-prefixed
@@ -50,6 +51,21 @@ def _sparkline(samples, width):
     return "".join(out), peak
 
 
+def _self_ms(spans):
+    """Self milliseconds of nested ``X`` spans on one track: each span's
+    duration less its direct children's.  → list of (cat, name, self_ms)."""
+    out, stack = [], []                      # stack: [cat, name, end, own]
+    for ts, dur, cat, name in sorted(spans, key=lambda s: (s[0], -s[1])):
+        while stack and stack[-1][2] <= ts:
+            c, n, _, own = stack.pop()
+            out.append((c, n, own / 1e3))
+        if stack:
+            stack[-1][3] -= dur
+        stack.append([cat, name, ts + dur, dur])
+    out.extend((c, n, own / 1e3) for c, n, _, own in stack)
+    return out
+
+
 def trace_summary(argv):
     ap = argparse.ArgumentParser(
         prog="diagnose trace-summary",
@@ -63,6 +79,7 @@ def trace_summary(argv):
 
     events = json.loads(Path(args.trace).read_text())["traceEvents"]
     spans = defaultdict(lambda: [0.0, 0])     # (cat, name) -> [ms, calls]
+    x_by_tid = defaultdict(list)              # tid -> (ts, dur, cat, name)
     reqs = defaultdict(dict)                  # uid -> lifecycle timestamps
     preempts = Counter()
     occupancy, slots = [], []
@@ -72,9 +89,8 @@ def trace_summary(argv):
         ph, name, uid = e.get("ph"), e.get("name", ""), \
             (e.get("args") or {}).get("uid")
         if ph == "X":
-            agg = spans[(e.get("cat", "event"), name)]
-            agg[0] += e.get("dur", 0.0) / 1e3
-            agg[1] += 1
+            x_by_tid[(e.get("pid"), e.get("tid"))].append(
+                (e["ts"], e.get("dur", 0.0), e.get("cat", "event"), name))
         elif ph == "i" and uid is not None:
             if name in ("submit", "first_token", "retire"):
                 reqs[uid][name] = e["ts"]
@@ -95,8 +111,17 @@ def trace_summary(argv):
         if ph == "i" and name == "route":
             routed[(e.get("args") or {}).get("replica", "?")] += 1
 
-    for cat, title in (("phase", "phase time"), ("kernel", "kernel spans"),
-                       ("swap", "swap traffic")):
+    # phases nest (a swap inside chain growth): each is charged its self
+    # time, and a step's own remainder is the ``other`` phase, so the table
+    # matches ``ServeReport.phase_ms``
+    for track in x_by_tid.values():
+        for cat, name, ms in _self_ms(track):
+            if cat == "step":
+                cat, name = "phase", "other"
+            agg = spans[(cat, name)]
+            agg[0] += ms
+            agg[1] += 1
+    for cat, title in (("phase", "phase time"), ("swap", "swap traffic")):
         rows = sorted(((n, ms, c) for (ct, n), (ms, c) in spans.items()
                        if ct == cat), key=lambda r: -r[1])
         if not rows:

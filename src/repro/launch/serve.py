@@ -91,9 +91,6 @@ def serve_stream(params, buffers, cfg, args):
     → (report, the ``Scheduler`` or, with ``--dp`` > 1, the ``Router``)."""
     rng = np.random.default_rng(args.seed)
     tracer = Tracer(capacity=args.trace_capacity) if args.trace else None
-    if tracer is not None:
-        from repro.kernels import ops
-        ops.set_kernel_tracer(tracer)       # eager kernel dispatches, if any
     scfg = serve_loop.SchedulerConfig(
         max_slots=args.max_slots, block_size=args.block_size,
         num_blocks=args.num_blocks, eos_id=args.eos_id,

@@ -112,6 +112,13 @@ def _logits(params, cfg, h, constrain=_NOOP):
     return constrain("logits", out)
 
 
+def _head(params, cfg, h, constrain=_NOOP):
+    """Final norm and logits of a paged serving forward."""
+    with jax.named_scope("head"):
+        h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+        return _logits(params, cfg, h, constrain)
+
+
 # ---------------------------------------------------------------------------
 # superblock body
 # ---------------------------------------------------------------------------
@@ -122,74 +129,76 @@ def _run_layer(p, b, cfg, p_pos: int, h, positions, mode, cache, index,
     ffn_kind = cfg.ffn_kind(p_pos)
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
-    hn = rmsnorm(p["attn_norm"], h, cfg.norm_eps)
-    # double pin: norm output stays S-sharded (bf16), then the SP all-gather
-    # happens exactly here — on the bf16 tensor, not an f32 norm intermediate
-    hn = constrain("attn_in", constrain("attn_in_sharded", hn))
-    if kind == "attn":
-        if cfg.elitekv.enabled:
-            if mode == "train":
-                a = elite_attention.apply_full(p["attn"], cfg, b, hn, positions,
-                                               constrain=constrain)
-            elif mode == "prefill" and paged is not None:
-                a, new_cache = elite_attention.apply_prefill_paged(
-                    p["attn"], cfg, b, hn, positions, cache,
-                    paged["slot_mapping"],
-                    block_tables=paged.get("block_tables"),
-                    prefix_lens=paged.get("prefix_lens"),
-                    block_size=paged.get("block_size", 0),
-                    constrain=constrain, mesh=paged.get("mesh"))
-            elif mode == "prefill":
-                a, new_cache = elite_attention.apply_prefill(
-                    p["attn"], cfg, b, hn, positions, cache, constrain=constrain)
-            elif paged is not None and paged.get("verify"):
-                a, new_cache = elite_attention.apply_verify_paged(
-                    p["attn"], cfg, b, hn, cache, paged["slot_mapping"],
-                    paged["block_tables"], paged["q_offsets"],
-                    paged["lengths"], paged["block_size"],
-                    use_kernel=paged.get("use_kernel", True),
-                    constrain=constrain, mesh=paged.get("mesh"))
-            elif paged is not None:
-                a, new_cache = elite_attention.apply_decode_paged(
-                    p["attn"], cfg, b, hn, cache, paged["slot_mapping"],
-                    paged["block_tables"], paged["lengths"],
-                    paged["block_size"], use_kernel=paged.get("use_kernel", True),
-                    constrain=constrain, mesh=paged.get("mesh"),
-                    sparse_topk=paged.get("sparse_topk", 0),
-                    sparse_recent=paged.get("sparse_recent", 0))
+    with jax.named_scope("attn"):
+        hn = rmsnorm(p["attn_norm"], h, cfg.norm_eps)
+        # double pin: norm output stays S-sharded (bf16), then the SP all-gather
+        # happens exactly here — on the bf16 tensor, not an f32 norm intermediate
+        hn = constrain("attn_in", constrain("attn_in_sharded", hn))
+        if kind == "attn":
+            if cfg.elitekv.enabled:
+                if mode == "train":
+                    a = elite_attention.apply_full(p["attn"], cfg, b, hn, positions,
+                                                   constrain=constrain)
+                elif mode == "prefill" and paged is not None:
+                    a, new_cache = elite_attention.apply_prefill_paged(
+                        p["attn"], cfg, b, hn, positions, cache,
+                        paged["slot_mapping"],
+                        block_tables=paged.get("block_tables"),
+                        prefix_lens=paged.get("prefix_lens"),
+                        block_size=paged.get("block_size", 0),
+                        constrain=constrain, mesh=paged.get("mesh"))
+                elif mode == "prefill":
+                    a, new_cache = elite_attention.apply_prefill(
+                        p["attn"], cfg, b, hn, positions, cache, constrain=constrain)
+                elif paged is not None and paged.get("verify"):
+                    a, new_cache = elite_attention.apply_verify_paged(
+                        p["attn"], cfg, b, hn, cache, paged["slot_mapping"],
+                        paged["block_tables"], paged["q_offsets"],
+                        paged["lengths"], paged["block_size"],
+                        use_kernel=paged.get("use_kernel", True),
+                        constrain=constrain, mesh=paged.get("mesh"))
+                elif paged is not None:
+                    a, new_cache = elite_attention.apply_decode_paged(
+                        p["attn"], cfg, b, hn, cache, paged["slot_mapping"],
+                        paged["block_tables"], paged["lengths"],
+                        paged["block_size"], use_kernel=paged.get("use_kernel", True),
+                        constrain=constrain, mesh=paged.get("mesh"),
+                        sparse_topk=paged.get("sparse_topk", 0),
+                        sparse_recent=paged.get("sparse_recent", 0))
+                else:
+                    a, new_cache = elite_attention.apply_decode(
+                        p["attn"], cfg, b, hn, index, cache, constrain=constrain)
             else:
-                a, new_cache = elite_attention.apply_decode(
-                    p["attn"], cfg, b, hn, index, cache, constrain=constrain)
-        else:
+                if mode == "train":
+                    a = gqa_attention.apply_full(p["attn"], cfg, hn, positions,
+                                                 constrain=constrain)
+                elif mode == "prefill":
+                    a, new_cache = gqa_attention.apply_prefill(
+                        p["attn"], cfg, hn, positions, cache, constrain=constrain)
+                else:
+                    a, new_cache = gqa_attention.apply_decode(
+                        p["attn"], cfg, hn, index, cache, constrain=constrain)
+        else:  # mamba
             if mode == "train":
-                a = gqa_attention.apply_full(p["attn"], cfg, hn, positions,
-                                             constrain=constrain)
+                a = mamba_lib.apply_full(p["attn"], cfg, hn, constrain=constrain)
             elif mode == "prefill":
-                a, new_cache = gqa_attention.apply_prefill(
-                    p["attn"], cfg, hn, positions, cache, constrain=constrain)
+                a, (conv_s, ssm_s) = mamba_lib.apply_full(
+                    p["attn"], cfg, hn, return_state=True, constrain=constrain)
+                new_cache = {"conv": conv_s.astype(cache["conv"].dtype), "ssm": ssm_s}
             else:
-                a, new_cache = gqa_attention.apply_decode(
-                    p["attn"], cfg, hn, index, cache, constrain=constrain)
-    else:  # mamba
-        if mode == "train":
-            a = mamba_lib.apply_full(p["attn"], cfg, hn, constrain=constrain)
-        elif mode == "prefill":
-            a, (conv_s, ssm_s) = mamba_lib.apply_full(p["attn"], cfg, hn, return_state=True,
+                a, new_cache = mamba_lib.apply_decode(p["attn"], cfg, hn, cache,
                                                       constrain=constrain)
-            new_cache = {"conv": conv_s.astype(cache["conv"].dtype), "ssm": ssm_s}
-        else:
-            a, new_cache = mamba_lib.apply_decode(p["attn"], cfg, hn, cache,
-                                                  constrain=constrain)
     h = constrain("residual", h + constrain("attn_out", a))
-    if ffn_kind != "none":
-        hn = constrain("attn_in", constrain(
-            "attn_in_sharded", rmsnorm(p["ffn_norm"], h, cfg.norm_eps)))
-        if ffn_kind == "moe":
-            f, aux = moe_lib.apply(p["ffn"], cfg, hn, impl=moe_impl, mesh=mesh,
-                                   data_axes=data_axes)
-        else:
-            f = mlp(p["ffn"], hn, constrain=constrain)
-        h = constrain("residual", h + constrain("ffn_out", f))
+    with jax.named_scope("mlp"):
+        if ffn_kind != "none":
+            hn = constrain("attn_in", constrain(
+                "attn_in_sharded", rmsnorm(p["ffn_norm"], h, cfg.norm_eps)))
+            if ffn_kind == "moe":
+                f, aux = moe_lib.apply(p["ffn"], cfg, hn, impl=moe_impl, mesh=mesh,
+                                       data_axes=data_axes)
+            else:
+                f = mlp(p["ffn"], hn, constrain=constrain)
+            h = constrain("residual", h + constrain("ffn_out", f))
     return h, aux, new_cache
 
 
@@ -345,8 +354,8 @@ def apply_prefill_paged(params, buffers, cfg, batch, pages, slot_mapping,
     → (logits [B,S,V], new_pages).
     """
     assert cfg.elitekv.enabled, "paged serving requires an EliteKV cache"
-    h = _embed_inputs(params, cfg, batch, cfg.dtype)
-    h = constrain("embed", h)
+    with jax.named_scope("embed"):
+        h = constrain("embed", _embed_inputs(params, cfg, batch, cfg.dtype))
     S = h.shape[1]
     positions = jnp.arange(S)
     paged = {"slot_mapping": slot_mapping, "mesh": mesh}
@@ -361,8 +370,7 @@ def apply_prefill_paged(params, buffers, cfg, batch, pages, slot_mapping,
         params, buffers, cfg, h, positions, mode="prefill",
         cache={"blocks": pages}, moe_impl=moe_impl, mesh=mesh,
         constrain=constrain, data_axes=data_axes, paged=paged)
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    return _logits(params, cfg, h, constrain), new_pages
+    return _head(params, cfg, h, constrain), new_pages
 
 
 def apply_decode_paged(params, buffers, cfg, batch, pages, slot_mapping,
@@ -382,7 +390,8 @@ def apply_decode_paged(params, buffers, cfg, batch, pages, slot_mapping,
     → (logits [B,1,V], new_pages).
     """
     assert cfg.elitekv.enabled, "paged serving requires an EliteKV cache"
-    h = _embed_step(params, cfg, batch)
+    with jax.named_scope("embed"):
+        h = _embed_step(params, cfg, batch)
     paged = {"slot_mapping": slot_mapping, "block_tables": block_tables,
              "lengths": lengths, "block_size": block_size,
              "use_kernel": use_kernel, "mesh": mesh,
@@ -391,8 +400,7 @@ def apply_decode_paged(params, buffers, cfg, batch, pages, slot_mapping,
         params, buffers, cfg, h, None, mode="decode",
         cache={"blocks": pages}, moe_impl=moe_impl, mesh=mesh,
         constrain=constrain, data_axes=data_axes, paged=paged)
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    return _logits(params, cfg, h, constrain), new_pages
+    return _head(params, cfg, h, constrain), new_pages
 
 
 def apply_verify_paged(params, buffers, cfg, batch, pages, slot_mapping,
@@ -413,7 +421,8 @@ def apply_verify_paged(params, buffers, cfg, batch, pages, slot_mapping,
     → (logits [B,W,V], new_pages).
     """
     assert cfg.elitekv.enabled, "paged serving requires an EliteKV cache"
-    h = _embed_step(params, cfg, batch)
+    with jax.named_scope("embed"):
+        h = _embed_step(params, cfg, batch)
     paged = {"slot_mapping": slot_mapping, "block_tables": block_tables,
              "q_offsets": q_offsets, "lengths": lengths,
              "block_size": block_size, "use_kernel": use_kernel,
@@ -422,8 +431,7 @@ def apply_verify_paged(params, buffers, cfg, batch, pages, slot_mapping,
         params, buffers, cfg, h, None, mode="decode",
         cache={"blocks": pages}, moe_impl=moe_impl, mesh=mesh,
         constrain=constrain, data_axes=data_axes, paged=paged)
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    return _logits(params, cfg, h, constrain), new_pages
+    return _head(params, cfg, h, constrain), new_pages
 
 
 def make_draft_params(params, cfg, draft_rank: int):

@@ -4,13 +4,13 @@ Three independent pieces, stdlib-only so every layer of the stack can
 depend on them without import cycles:
 
 * ``trace``    — a low-overhead ring-buffer event tracer (spans, instants,
-                 counters) the scheduler, block pool and kernel wrappers
-                 emit structured events into.
+                 counters) the scheduler and block pool emit structured
+                 events into.
 * ``metrics``  — a process-wide registry of counters / gauges / histograms
                  with Prometheus text-format and JSON export.
 * ``timeline`` — export of the event stream as Chrome trace-event JSON,
                  viewable in Perfetto (https://ui.perfetto.dev), one track
-                 per pool slot plus scheduler / pool / kernel tracks.
+                 per pool slot plus scheduler / pool tracks.
 """
 from repro.obs.metrics import REGISTRY, MetricsRegistry
 from repro.obs.trace import NULL_TRACER, Event, Tracer

@@ -2,7 +2,7 @@
 
 The output loads directly in Perfetto (https://ui.perfetto.dev — "Open trace
 file") or chrome://tracing: one process, one *thread track* per tracer track
-— ``scheduler`` (phase spans), ``kernel`` (dispatch spans), ``pool`` (block
+— ``scheduler`` (step spans enclosing their phase spans), ``pool`` (block
 churn instants + occupancy counter), and one ``slot<i>`` row per scheduler
 slot showing request residency spans.  Format reference:
 https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU
@@ -20,7 +20,7 @@ from repro.obs.trace import Event, Tracer
 
 #: Fixed thread ids for the well-known tracks (stable across runs so diffs
 #: of two timelines line up); other tracks (slots) get ids after these.
-_PINNED_TRACKS = ("scheduler", "kernel", "pool")
+_PINNED_TRACKS = ("scheduler", "pool")
 
 
 def _track_order(tracks: Iterable[str]) -> List[str]:

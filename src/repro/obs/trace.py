@@ -16,8 +16,8 @@ targets:
 * ``C`` — a counter sample (pool blocks in use, occupied slots)
 
 Every event carries a ``track`` — the timeline row it renders on:
-``"scheduler"`` (phase spans), ``"pool"`` (block churn), ``"kernel"``
-(opt-in dispatch spans), and ``"slot<i>"`` (per-slot request lifecycles).
+``"scheduler"`` (step and phase spans), ``"pool"`` (block churn), and
+``"slot<i>"`` (per-slot request lifecycles).
 
 Disabled tracers (``Tracer(enabled=False)`` or the shared ``NULL_TRACER``)
 reduce every emit to one attribute check, so instrumented code paths need no

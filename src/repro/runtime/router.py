@@ -265,17 +265,7 @@ class Router:
         w0 = self.board.waiting[i]
         f0 = len(rep.finished)
         rep.t = self.t                       # lockstep: router owns the clock
-        s0 = time.perf_counter()
-        before = rep._measured_phase_ms()
         alive = rep.step()
-        # mirror Scheduler.run's per-step wall accounting (the router drives
-        # step() directly): residual host time lands in phase "other" so each
-        # replica's sum(phase_ms) still equals its step_wall_ms_total
-        dt_ms = (time.perf_counter() - s0) * 1e3
-        rep._step_wall_ms_total += dt_ms
-        other = dt_ms - (rep._measured_phase_ms() - before)
-        rep._phase_ms["other"] += max(0.0, other)
-        rep._m_phase["other"].inc(max(0.0, other))
         w1 = len(rep.waiting)
         r1 = sum(1 for s in rep.slots if s is not None)
         retires = len(rep.finished) - f0

@@ -73,13 +73,18 @@ in an LRU until the allocator actually needs their blocks.
 
 Observability (docs/observability.md): the scheduler accepts a
 ``repro.obs.trace.Tracer`` and a ``repro.obs.metrics.MetricsRegistry``.  Every
-step is decomposed into host-observable **phases** (``PHASES``) — prefill /
-decode / draft / verify forwards, sampling, speculative accept bookkeeping,
-swap copies, plus an ``other`` residual — whose wall totals land in
-``ServeReport.phase_ms`` and, when a tracer is attached, as spans on the
-``scheduler`` timeline track; per-request lifecycle events (submit, admit,
-prefill chunks, preempt, retire) land on per-slot tracks and the pool emits
-its own alloc/free/swap/truncate events.  Instrumentation is passive: it
+``step()`` times itself and is decomposed into host-observable **phases**
+(``PHASES``) — prefill / decode / draft / verify forwards, sampling,
+speculative accept bookkeeping, swap copies, admission, chain growth, input
+packing, plus the step's own ``other`` remainder.  Phases nest (a swap-out
+inside chain growth) and each is charged its *self* time, so the totals in
+``ServeReport.phase_ms`` sum to ``step_wall_ms_total`` for any caller of
+``step()``.  The step and each phase are also ``jax.profiler.TraceAnnotation``
+spans (``sched.step``, ``sched.<phase>``), on the device trace's clock in
+any profile, and, when a tracer is attached, spans on the ``scheduler``
+timeline track; per-request lifecycle events (submit, admit, prefill chunks,
+preempt, retire) land on per-slot tracks and the pool emits its own
+alloc/free/swap/truncate events.  Instrumentation is passive: it
 reads clocks and appends host-side records, never touching PRNG or
 scheduling state, so a traced run emits bit-identical tokens to an untraced
 one (tests/test_obs.py pins this).
@@ -103,13 +108,16 @@ from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER
 
 #: Host-observable phases of one scheduler step (``ServeReport.phase_ms``
-#: keys — all always present, zero-valued when a phase never ran).  ``other``
-#: is the per-step residual (admission, growth bookkeeping, host packing),
-#: computed so the phase totals sum to ``step_wall_ms_total``.  The jitted
-#: forwards are opaque to host timing, so the embedding dispatch is folded
-#: into its enclosing forward phase.
+#: keys — all always present, zero-valued when a phase never ran), each
+#: charged its self time.  ``admit`` is the admission loop (prefix lookup
+#: included), ``grow`` the chain-growth loops (a preemption nests inside),
+#: ``pack`` the host input arrays of a forward; ``other`` is the step's own
+#: remainder (gauges, counters, lane ordering, token bookkeeping), so the
+#: phase totals sum to ``step_wall_ms_total``.  The jitted forwards are
+#: opaque to host timing, so their input puts and the embedding dispatch are
+#: folded into the enclosing forward phase.
 PHASES = ("prefill", "decode", "draft", "verify", "sample", "accept",
-          "swap", "other")
+          "swap", "admit", "grow", "pack", "other")
 
 
 def make_prefill_step(cfg: ModelConfig, mesh=None, constrain=None,
@@ -482,9 +490,9 @@ class ServeReport:
     mean_selected_blocks: float = 0.0     # blocks attended per lane-step
     mean_candidate_blocks: float = 0.0    # resident blocks per lane-step
     phase_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
-    #   ^ wall ms per step phase over the whole run (keys == PHASES; a phase
-    #     that never ran reports exactly 0.0).  ``other`` is the residual, so
-    #     sum(phase_ms.values()) ≈ step_wall_ms_total.
+    #   ^ self wall ms per step phase over the whole run (keys == PHASES; a
+    #     phase that never ran reports exactly 0.0); they sum to
+    #     step_wall_ms_total.
     step_wall_ms_total: float = 0.0       # Σ wall ms of every step() call
     trace_events: int = 0                 # events emitted to the tracer
     trace_dropped: int = 0                # events the ring buffer evicted
@@ -603,6 +611,7 @@ class Scheduler:
         # -- observability state (docs/observability.md) ---------------------
         self._phase_ms = {p: 0.0 for p in PHASES}
         self._step_wall_ms_total = 0.0
+        self._phase_stack: List[List[Any]] = []   # open [phase, since]
         m = self.metrics
         self._m_submitted = m.counter(
             "serve_requests_submitted_total", "requests submitted")
@@ -612,6 +621,11 @@ class Scheduler:
             "serve_tokens_decoded_total", "tokens appended by decode/verify")
         self._m_prefill_tokens = m.counter(
             "serve_prefill_tokens_total", "tokens cached by prefill forwards")
+        self._m_prefill_calls = m.counter(
+            "serve_prefill_calls_total", "prefill forward calls made")
+        self._m_prefill_capacity = m.counter(
+            "serve_prefill_capacity_tokens_total",
+            "token rows of the prefill calls made, padding included")
         self._m_preemptions = m.counter(
             "serve_preemptions_total", "residents evicted on OutOfBlocks")
         self._m_swap_outs = m.counter(
@@ -633,8 +647,12 @@ class Scheduler:
         self._m_ttft_ms = m.histogram(
             "serve_ttft_ms", "request arrival to first token, wall ms")
         self._m_phase = {p: m.counter(f"serve_phase_{p}_ms_total",
-                                      f"total wall ms spent in the {p} phase")
+                                      f"self wall ms spent in the {p} phase")
                          for p in PHASES}
+        self._m_steps = m.counter("serve_steps_total", "step() calls")
+        self._m_step_wall = m.counter(
+            "serve_step_wall_ms_total",
+            "wall ms inside step() (the phase totals sum to it)")
         # prefix-cache family (always registered; zero-valued when the cache
         # is off so exported metric sets stay schema-stable for check_trace)
         self._m_pc_hits = m.counter(
@@ -755,21 +773,34 @@ class Scheduler:
     @contextlib.contextmanager
     def _phase(self, name: str, **args):
         """Attribute the enclosed wall time to step phase ``name`` — into
-        ``phase_ms``, the metrics registry, and (when tracing) a span on the
-        ``scheduler`` track.  Phases never nest (the residual ``other`` would
-        double-count), which tools/check_trace.py can verify from the
-        exported timeline."""
-        with self.trace.span(name, track="scheduler", cat="phase", **args):
-            t0 = time.perf_counter()
+        ``phase_ms``, the metrics registry, a ``sched.<name>`` profiler
+        annotation and (when tracing) a span on the ``scheduler`` track.
+        Phases nest: entering one charges the enclosing phase its time so
+        far, which resumes on exit, so every total is self time and none
+        double-counts."""
+        with jax.profiler.TraceAnnotation(f"sched.{name}"), \
+                self.trace.span(name, track="scheduler", cat="phase", **args):
+            stack = self._phase_stack
+            now = time.perf_counter()
+            if stack:
+                self._charge(stack[-1], now)
+            frame = [name, now]
+            stack.append(frame)
             try:
                 yield
             finally:
-                dt_ms = (time.perf_counter() - t0) * 1e3
-                self._phase_ms[name] += dt_ms
-                self._m_phase[name].inc(dt_ms)
+                now = time.perf_counter()
+                stack.pop()
+                self._charge(frame, now)
+                if stack:
+                    stack[-1][1] = now
 
-    def _measured_phase_ms(self) -> float:
-        return sum(v for k, v in self._phase_ms.items() if k != "other")
+    def _charge(self, frame: List[Any], now: float) -> None:
+        """Charge ``frame``'s phase its time since it last resumed."""
+        dt_ms = (now - frame[1]) * 1e3
+        frame[1] = now
+        self._phase_ms[frame[0]] += dt_ms
+        self._m_phase[frame[0]].inc(dt_ms)
 
     def _stuck_report(self, max_steps: int) -> str:
         """Diagnostic payload for the did-not-drain failure: per-request
@@ -1026,22 +1057,26 @@ class Scheduler:
         src = req.prefill_source()
         sp = len(src)
         pos = req.prefill_pos
-        if not self._grow_or_preempt(req, sp, write_from=pos):
+        with self._phase("grow"):
+            grown = self._grow_or_preempt(req, sp, write_from=pos)
+        if not grown:
             return                          # req evicted itself — retry later
         n = sp - pos
         pad = -(-n // self.scfg.prefill_bucket) * self.scfg.prefill_bucket
-        tokens = np.zeros((1, pad), np.int32)
-        tokens[0, :n] = src[pos:]
-        sm = self.pool.prefill_slot_mapping(req.uid, pos, n, pad)[None]
+        with self._phase("pack"):
+            tokens = np.zeros((1, pad), np.int32)
+            tokens[0, :n] = src[pos:]
+            sm = self.pool.prefill_slot_mapping(req.uid, pos, n, pad)[None]
+            if pos > 0:
+                bt = self.pool.block_table_array(
+                    [req.uid], self.scfg.max_blocks_per_seq)
+                starts = np.asarray([pos], np.int32)
         with self._phase("prefill", lanes=1, tokens=n):
             if pos == 0:
                 logits, self.pool.pages = self._prefill(
                     self.params, self.buffers, jnp.asarray(tokens),
                     self.pool.pages, jnp.asarray(sm))
             else:
-                bt = self.pool.block_table_array(
-                    [req.uid], self.scfg.max_blocks_per_seq)
-                starts = np.asarray([pos], np.int32)
                 logits, self.pool.pages = self._prefill_batch(
                     self.params, self.buffers, jnp.asarray(tokens),
                     self.pool.pages, jnp.asarray(sm), jnp.asarray(starts),
@@ -1050,6 +1085,8 @@ class Scheduler:
         self.trace.instant("prefill_chunk", track=f"slot{slot}",
                            cat="request", uid=req.uid, start=pos, n=n)
         self._m_prefill_tokens.inc(n)
+        self._m_prefill_calls.inc()
+        self._m_prefill_capacity.inc(pad)
         req.prefill_pos = sp
         if self.bm.prefix is not None:
             self.bm.register_prefix(req.uid, src[:sp])
@@ -1084,32 +1121,37 @@ class Scheduler:
                       for i, s in enumerate(self.slots)
                       if s is not None
                       and s.prefill_pos < len(s.prefill_source()))
+        if not cand:
+            return
         selected: List[Tuple[int, Request, int, int]] = []
-        for _, _, slot in cand:
-            if len(selected) >= scfg.chunk_lanes:
-                break
-            req = self.slots[slot]
-            if req is None:                 # evicted by an earlier growth
-                continue
-            n = min(chunk, len(req.prefill_source()) - req.prefill_pos)
-            if self._grow_or_preempt(req, req.prefill_pos + n,
-                                     write_from=req.prefill_pos):
-                selected.append((slot, req, req.prefill_pos, n))
+        with self._phase("grow"):
+            for _, _, slot in cand:
+                if len(selected) >= scfg.chunk_lanes:
+                    break
+                req = self.slots[slot]
+                if req is None:             # evicted by an earlier growth
+                    continue
+                n = min(chunk, len(req.prefill_source()) - req.prefill_pos)
+                if self._grow_or_preempt(req, req.prefill_pos + n,
+                                         write_from=req.prefill_pos):
+                    selected.append((slot, req, req.prefill_pos, n))
         selected = [(s, r, st, n) for s, r, st, n in selected
                     if self.slots[s] is r]  # drop lanes evicted after selection
         if not selected:
             return
         lanes = scfg.chunk_lanes
-        tokens = np.zeros((lanes, chunk), np.int32)
-        sms = np.full((lanes, chunk), self.pool.oob_slot, np.int32)
-        starts = np.zeros((lanes,), np.int32)
-        seq_ids: List[Optional[int]] = [None] * lanes
-        for lane, (slot, req, start, n) in enumerate(selected):
-            tokens[lane, :n] = req.prefill_source()[start:start + n]
-            sms[lane] = self.pool.prefill_slot_mapping(req.uid, start, n, chunk)
-            starts[lane] = start            # chunk offset == cached prefix len
-            seq_ids[lane] = req.uid
-        bt = self.pool.block_table_array(seq_ids, scfg.max_blocks_per_seq)
+        with self._phase("pack"):
+            tokens = np.zeros((lanes, chunk), np.int32)
+            sms = np.full((lanes, chunk), self.pool.oob_slot, np.int32)
+            starts = np.zeros((lanes,), np.int32)
+            seq_ids: List[Optional[int]] = [None] * lanes
+            for lane, (slot, req, start, n) in enumerate(selected):
+                tokens[lane, :n] = req.prefill_source()[start:start + n]
+                sms[lane] = self.pool.prefill_slot_mapping(req.uid, start, n,
+                                                           chunk)
+                starts[lane] = start        # chunk offset == cached prefix len
+                seq_ids[lane] = req.uid
+            bt = self.pool.block_table_array(seq_ids, scfg.max_blocks_per_seq)
         n_toks = sum(n for _, _, _, n in selected)
         with self._phase("prefill", lanes=len(selected), tokens=n_toks):
             logits, self.pool.pages = self._prefill_batch(
@@ -1118,6 +1160,8 @@ class Scheduler:
                 jnp.asarray(starts))
             jax.block_until_ready(logits)
         self._m_prefill_tokens.inc(n_toks)
+        self._m_prefill_calls.inc()
+        self._m_prefill_capacity.inc(lanes * chunk)
         self.prefill_chunks += 1
         self._prefill_lanes_total += len(selected)
         for lane, (slot, req, start, n) in enumerate(selected):
@@ -1164,8 +1208,31 @@ class Scheduler:
     # -- one scheduler iteration -------------------------------------------
     def step(self) -> bool:
         """Admit + chunk-prefill + decode (or draft/verify) once.  Returns
-        False when drained."""
-        self._try_admit()
+        False when drained.
+
+        Times itself: the call's wall lands in ``serve_step_wall_ms_total``
+        and ``step_wall_ms_total``, and the step's own remainder outside
+        every phase in ``other``, so the phase totals sum to it whoever
+        drives the loop."""
+        with jax.profiler.TraceAnnotation("sched.step"), \
+                self.trace.span("step", track="scheduler", cat="step"):
+            start = time.perf_counter()
+            frame = ["other", start]
+            self._phase_stack.append(frame)
+            try:
+                return self._step()
+            finally:
+                now = time.perf_counter()
+                self._phase_stack.pop()
+                self._charge(frame, now)
+                dt_ms = (now - start) * 1e3
+                self._step_wall_ms_total += dt_ms
+                self._m_step_wall.inc(dt_ms)
+                self._m_steps.inc()
+
+    def _step(self) -> bool:
+        with self._phase("admit"):
+            self._try_admit()
         self._prefill_work()
         occupied = [i for i, s in enumerate(self.slots) if s is not None]
         self.peak_slots = max(self.peak_slots, len(occupied))
@@ -1211,13 +1278,14 @@ class Scheduler:
         """Plain one-token decode over every decode-ready lane (one forward).
         Returns False when no lane was live (waiting on arrivals/prefill)."""
         grown: Dict[int, int] = {}          # slot → position of the new token
-        for _, _, i in order:
-            req = self.slots[i]
-            if req is None:
-                continue                    # evicted by an older lane's growth
-            cur = self.pool.length(req.uid)
-            if self._grow_or_preempt(req, cur + 1, write_from=cur):
-                grown[i] = cur
+        with self._phase("grow"):
+            for _, _, i in order:
+                req = self.slots[i]
+                if req is None:
+                    continue                # evicted by an older lane's growth
+                cur = self.pool.length(req.uid)
+                if self._grow_or_preempt(req, cur + 1, write_from=cur):
+                    grown[i] = cur
         active = [i for i in grown if self.slots[i] is not None]
         self._occupancy.append(
             self._blocks_referenced() / self.pool.num_blocks)
@@ -1228,27 +1296,28 @@ class Scheduler:
 
         scfg = self.scfg
         B = scfg.max_slots
-        tokens = np.zeros((B, 1), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        temps = np.zeros((B,), np.float32)
-        top_ps = np.ones((B,), np.float32)
-        seeds = np.zeros((B,), np.int32)
-        counts = np.zeros((B,), np.int32)
-        seq_ids: List[Optional[int]] = [None] * B
-        positions = [0] * B
-        for i in active:
-            req = self.slots[i]
-            cur = grown[i]                  # chain already grown above
-            tokens[i, 0] = req.generated[-1]
-            lengths[i] = cur + 1
-            seq_ids[i] = req.uid
-            positions[i] = cur
-            temps[i] = req.temperature
-            top_ps[i] = req.top_p
-            seeds[i] = req.seed
-            counts[i] = len(req.generated)  # token index within the request
-        sm = self.pool.slot_mapping(seq_ids, positions)
-        bt = self.pool.block_table_array(seq_ids, scfg.max_blocks_per_seq)
+        with self._phase("pack"):
+            tokens = np.zeros((B, 1), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_ps = np.ones((B,), np.float32)
+            seeds = np.zeros((B,), np.int32)
+            counts = np.zeros((B,), np.int32)
+            seq_ids: List[Optional[int]] = [None] * B
+            positions = [0] * B
+            for i in active:
+                req = self.slots[i]
+                cur = grown[i]              # chain already grown above
+                tokens[i, 0] = req.generated[-1]
+                lengths[i] = cur + 1
+                seq_ids[i] = req.uid
+                positions[i] = cur
+                temps[i] = req.temperature
+                top_ps[i] = req.top_p
+                seeds[i] = req.seed
+                counts[i] = len(req.generated)  # token index within request
+            sm = self.pool.slot_mapping(seq_ids, positions)
+            bt = self.pool.block_table_array(seq_ids, scfg.max_blocks_per_seq)
 
         t0 = time.perf_counter()
         with self._phase("decode", lanes=len(active)):
@@ -1325,14 +1394,15 @@ class Scheduler:
         B = scfg.max_slots
         W = k + 1
         windows: Dict[int, Tuple[int, int]] = {}   # slot → (cur, w)
-        for _, _, i in order:
-            req = self.slots[i]
-            if req is None:
-                continue                    # evicted by an older lane's growth
-            cur = self.pool.length(req.uid)
-            w = min(k, req.max_new_tokens - len(req.generated))
-            if self._grow_or_preempt(req, cur + w + 1, write_from=cur):
-                windows[i] = (cur, w)
+        with self._phase("grow"):
+            for _, _, i in order:
+                req = self.slots[i]
+                if req is None:
+                    continue                # evicted by an older lane's growth
+                cur = self.pool.length(req.uid)
+                w = min(k, req.max_new_tokens - len(req.generated))
+                if self._grow_or_preempt(req, cur + w + 1, write_from=cur):
+                    windows[i] = (cur, w)
         active = [i for i in windows if self.slots[i] is not None]
         self._occupancy.append(
             self._blocks_referenced() / self.pool.num_blocks)
@@ -1346,11 +1416,12 @@ class Scheduler:
         # was grown to its full window above): build them once, reuse for
         # all k draft forwards and the verify forward.  Lanes that fall out
         # of a shorter window mid-draft are masked by length 0 + oob slots.
-        seq_ids_act: List[Optional[int]] = [None] * B
-        for i in active:
-            seq_ids_act[i] = self.slots[i].uid
-        bt = jnp.asarray(self.pool.block_table_array(
-            seq_ids_act, scfg.max_blocks_per_seq))
+        with self._phase("pack"):
+            seq_ids_act: List[Optional[int]] = [None] * B
+            for i in active:
+                seq_ids_act[i] = self.slots[i].uid
+            bt = jnp.asarray(self.pool.block_table_array(
+                seq_ids_act, scfg.max_blocks_per_seq))
         # -- draft: k cheap truncated-rank decode forwards, batched over lanes
         drafts: Dict[int, List[int]] = {i: [] for i in active}
         dlogits: Dict[int, List[np.ndarray]] = {i: [] for i in active}
@@ -1359,26 +1430,27 @@ class Scheduler:
             live = [i for i in active if windows[i][1] > j]
             if not live:
                 break
-            tokens = np.zeros((B, 1), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            temps = np.zeros((B,), np.float32)
-            top_ps = np.ones((B,), np.float32)
-            seeds = np.zeros((B,), np.int32)
-            counts = np.zeros((B,), np.int32)
-            seq_ids: List[Optional[int]] = [None] * B
-            positions = [0] * B
-            for i in live:
-                req = self.slots[i]
-                cur, _ = windows[i]
-                tokens[i, 0] = xs[i]
-                lengths[i] = cur + j + 1
-                seq_ids[i] = req.uid
-                positions[i] = cur + j
-                temps[i] = req.temperature
-                top_ps[i] = req.top_p
-                seeds[i] = req.seed
-                counts[i] = len(req.generated) + j  # index of the proposal
-            sm = self.pool.slot_mapping(seq_ids, positions)
+            with self._phase("pack"):
+                tokens = np.zeros((B, 1), np.int32)
+                lengths = np.zeros((B,), np.int32)
+                temps = np.zeros((B,), np.float32)
+                top_ps = np.ones((B,), np.float32)
+                seeds = np.zeros((B,), np.int32)
+                counts = np.zeros((B,), np.int32)
+                seq_ids: List[Optional[int]] = [None] * B
+                positions = [0] * B
+                for i in live:
+                    req = self.slots[i]
+                    cur, _ = windows[i]
+                    tokens[i, 0] = xs[i]
+                    lengths[i] = cur + j + 1
+                    seq_ids[i] = req.uid
+                    positions[i] = cur + j
+                    temps[i] = req.temperature
+                    top_ps[i] = req.top_p
+                    seeds[i] = req.seed
+                    counts[i] = len(req.generated) + j  # proposal's index
+                sm = self.pool.slot_mapping(seq_ids, positions)
             with self._phase("draft", j=j, lanes=len(live)):
                 logits, self.pool.pages = self._decode(
                     self.draft_params, self.buffers, jnp.asarray(tokens),
@@ -1404,18 +1476,20 @@ class Scheduler:
                 xs[i] = int(nxt[i])
 
         # -- verify: all k+1 window positions per lane in ONE forward --------
-        tokens = np.zeros((B, W), np.int32)
-        sms = np.full((B, W), self.pool.oob_slot, np.int32)
-        offs = np.zeros((B,), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        for i in active:
-            req = self.slots[i]
-            cur, w = windows[i]
-            tokens[i, 0] = req.generated[-1]
-            tokens[i, 1:1 + w] = drafts[i][:w]
-            sms[i] = self.pool.prefill_slot_mapping(req.uid, cur, w + 1, W)
-            offs[i] = cur
-            lengths[i] = cur + w + 1
+        with self._phase("pack"):
+            tokens = np.zeros((B, W), np.int32)
+            sms = np.full((B, W), self.pool.oob_slot, np.int32)
+            offs = np.zeros((B,), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            for i in active:
+                req = self.slots[i]
+                cur, w = windows[i]
+                tokens[i, 0] = req.generated[-1]
+                tokens[i, 1:1 + w] = drafts[i][:w]
+                sms[i] = self.pool.prefill_slot_mapping(req.uid, cur, w + 1,
+                                                        W)
+                offs[i] = cur
+                lengths[i] = cur + w + 1
         with self._phase("verify", lanes=len(active)):
             logits, self.pool.pages = self._verify(
                 self.params, self.buffers, jnp.asarray(tokens), self.pool.pages,
@@ -1498,19 +1572,7 @@ class Scheduler:
             self.submit(r)
         t0 = time.perf_counter()
         steps = 0
-        while True:
-            s0 = time.perf_counter()
-            before = self._measured_phase_ms()
-            alive = self.step()
-            dt_ms = (time.perf_counter() - s0) * 1e3
-            self._step_wall_ms_total += dt_ms
-            # residual host time this step (admission, growth bookkeeping,
-            # packing) — keeps Σ phase_ms == step_wall_ms_total
-            other = dt_ms - (self._measured_phase_ms() - before)
-            self._phase_ms["other"] += max(0.0, other)
-            self._m_phase["other"].inc(max(0.0, other))
-            if not alive:
-                break
+        while self.step():
             steps += 1
             if steps > max_steps:
                 raise RuntimeError(self._stuck_report(max_steps))

@@ -45,10 +45,10 @@ def test_tracer_ring_drops_oldest():
 
 def test_tracer_span_times_and_nests_args():
     tr = Tracer()
-    with tr.span("work", track="kernel", cat="kernel", shape="(2,3)"):
+    with tr.span("work", track="pool", cat="swap", shape="(2,3)"):
         pass
     (ev,) = tr.events()
-    assert ev.ph == "X" and ev.dur >= 0.0 and ev.track == "kernel"
+    assert ev.ph == "X" and ev.dur >= 0.0 and ev.track == "pool"
     assert ev.arg("shape") == "(2,3)"
     assert ev.args_dict() == {"shape": "(2,3)"}
 
@@ -86,8 +86,8 @@ def test_chrome_export_structure_and_track_order():
     names = {e["args"]["name"]: e["tid"] for e in doc["traceEvents"]
              if e["ph"] == "M" and e["name"] == "thread_name"}
     # pinned tracks take the low tids in fixed order; slots follow
-    assert names["scheduler"] == 0 and names["kernel"] == 1 \
-        and names["pool"] == 2 and names["slot0"] == 3
+    assert names["scheduler"] == 0 and names["pool"] == 1 \
+        and names["slot0"] == 2 and "kernel" not in names
     phs = {e["ph"] for e in doc["traceEvents"]}
     assert {"M", "B", "E", "i", "C", "X"} <= phs
 
@@ -184,6 +184,42 @@ def test_traced_run_tokens_bit_identical(tiny_elite_cfg, tiny_elite_model,
         sum(len(r.generated) for r in s1.finished)
 
 
+def test_swap_nests_in_grow_or_admit_counted_once(tiny_elite_cfg,
+                                                  tiny_elite_model):
+    """Under swap eviction a swap-out runs inside chain growth and a
+    swap-in inside admission: every ``swap`` phase span sits inside a
+    ``grow`` or ``admit`` span of its step, the enclosing phase is charged
+    only its own time, and the self times still sum to the step wall."""
+    params, buffers = tiny_elite_model
+    tr = Tracer()
+    s = serve_loop.Scheduler(params, buffers, tiny_elite_cfg, _scfg(6),
+                             tracer=tr)
+    rep = s.run(_reqs(tiny_elite_cfg))
+    assert rep.swap_outs > 0 and rep.swap_ins > 0
+    assert rep.phase_ms["swap"] > 0.0
+    wall = rep.step_wall_ms_total
+    assert abs(sum(rep.phase_ms.values()) - wall) <= 0.02 * wall
+
+    spans = [e for e in tr.events() if e.ph == "X" and e.track == "scheduler"]
+
+    def inside(e, name):
+        return [o for o in spans if o.name == name and o.ts <= e.ts
+                and e.ts + e.dur <= o.ts + o.dur]
+
+    swaps = [e for e in spans if e.name == "swap"]
+    assert {e.arg("direction") for e in swaps} == {"in", "out"}
+    for e in swaps:
+        (parent,) = inside(e, "admit" if e.arg("direction") == "in"
+                           else "grow")
+        assert inside(parent, "step")
+    for name in ("admit", "grow"):
+        total = sum(e.dur for e in spans if e.name == name) * 1e3
+        nested = sum(e.dur for e in swaps if inside(e, name)) * 1e3
+        # the phase's self time: its spans less the swaps inside them
+        assert rep.phase_ms[name] == pytest.approx(total - nested,
+                                                   rel=0.05, abs=0.5)
+
+
 def test_untraced_scheduler_emits_nothing(tiny_elite_cfg, tiny_elite_model):
     params, buffers = tiny_elite_model
     s = serve_loop.Scheduler(params, buffers, tiny_elite_cfg, _scfg(64))
@@ -238,6 +274,7 @@ def test_trace_summary_cli(tiny_elite_cfg, tiny_elite_model, tmp_path,
     diagnose.main(["trace-summary", str(path)])
     out = capsys.readouterr().out
     assert "phase time" in out and "requests (2 submitted, 2 retired)" in out
+    assert "other" in out and "grow" in out       # self time per phase
     assert "pool occupancy" in out
 
 

@@ -10,6 +10,7 @@ from repro.configs import make_inputs
 from repro.core.cache import (attn_cache_floats_per_token, cache_ratio,
                               measured_cache_bytes, model_cache_floats_per_token)
 from repro.models import lm
+from repro.obs import MetricsRegistry
 from repro.runtime import serve_loop
 
 
@@ -68,27 +69,35 @@ def test_train_driver_runs(capsys):
 # per-step phase breakdown (docs/observability.md)
 # ---------------------------------------------------------------------------
 
-def _stream_run(params, buffers, cfg, speculate=0):
+def _stream_reqs(cfg):
     rng = np.random.default_rng(9)
-    reqs = [serve_loop.Request(
+    return [serve_loop.Request(
         uid=i,
         prompt=rng.integers(0, cfg.vocab_size,
                             int(rng.integers(6, 14))).astype(np.int32),
         max_new_tokens=6, arrival=i * 0.5) for i in range(3)]
-    scfg = serve_loop.SchedulerConfig(
+
+
+def _stream_scfg(prefill_chunk_tokens=4, **kw):
+    return serve_loop.SchedulerConfig(
         max_slots=2, block_size=4, num_blocks=64, max_len=32,
-        prefill_bucket=4, prefill_chunk_tokens=4, speculate_k=speculate)
-    sched = serve_loop.Scheduler(params, buffers, cfg, scfg)
-    return sched.run(reqs)
+        prefill_bucket=4, prefill_chunk_tokens=prefill_chunk_tokens, **kw)
+
+
+def _stream_run(params, buffers, cfg, speculate=0):
+    sched = serve_loop.Scheduler(params, buffers, cfg,
+                                 _stream_scfg(speculate_k=speculate))
+    return sched.run(_stream_reqs(cfg))
 
 
 def test_phase_breakdown_plain_decode(tiny_elite_cfg, tiny_elite_model):
     """Plain decode: phase keys are exactly PHASES, the phases that ran are
-    positive, speculative phases are exactly zero, and the breakdown sums to
-    the measured step wall time (the "other" residual closes the gap)."""
+    positive, speculative phases are exactly zero, and the self times sum to
+    the measured step wall time (the step's own remainder is "other")."""
     rep = _stream_run(*tiny_elite_model, tiny_elite_cfg)
     assert set(rep.phase_ms) == set(serve_loop.PHASES)
-    for phase in ("prefill", "decode", "sample"):
+    for phase in ("prefill", "decode", "sample", "admit", "grow", "pack",
+                  "other"):
         assert rep.phase_ms[phase] > 0.0, phase
     for phase in ("draft", "verify", "accept"):
         assert rep.phase_ms[phase] == 0.0, phase   # never ran ⇒ exactly 0
@@ -130,8 +139,79 @@ def test_phase_breakdown_speculative(tiny_elite_cfg, tiny_elite_model):
     """Speculative decode routes steps through draft/verify/accept instead
     of the plain decode phase; the sum invariant must still hold."""
     rep = _stream_run(*tiny_elite_model, tiny_elite_cfg, speculate=2)
-    for phase in ("draft", "verify", "accept"):
+    for phase in ("draft", "verify", "accept", "grow", "pack"):
         assert rep.phase_ms[phase] > 0.0, phase
     assert rep.phase_ms["decode"] == 0.0           # no plain decode steps ran
     total = rep.step_wall_ms_total
     assert abs(sum(rep.phase_ms.values()) - total) <= 0.02 * total + 1.0
+
+
+def _assert_phases_sum_to_wall(phase_ms, wall_ms):
+    assert wall_ms > 0.0
+    assert abs(sum(phase_ms.values()) - wall_ms) <= 0.02 * wall_ms
+
+
+def test_step_driven_directly_times_itself(tiny_elite_cfg, tiny_elite_model):
+    """A caller that drives ``step()`` itself (no ``run()``), as the serving
+    benchmark's harness does, still gets the step wall and the phase self times, in
+    the report and in the registry, and they agree."""
+    params, buffers = tiny_elite_model
+    reg = MetricsRegistry()
+    sched = serve_loop.Scheduler(params, buffers, tiny_elite_cfg,
+                                 _stream_scfg(), metrics=reg)
+    for r in _stream_reqs(tiny_elite_cfg):
+        sched.submit(r)
+    calls = 1
+    while sched.step():
+        calls += 1
+    rep = sched.report(1.0)
+    _assert_phases_sum_to_wall(rep.phase_ms, rep.step_wall_ms_total)
+    assert rep.phase_ms["other"] > 0.0
+    assert reg.get("serve_steps_total").value == calls
+    assert reg.get("serve_step_wall_ms_total").value == pytest.approx(
+        rep.step_wall_ms_total)
+    for p in serve_loop.PHASES:
+        assert reg.get(f"serve_phase_{p}_ms_total").value == pytest.approx(
+            rep.phase_ms[p]), p
+
+
+@pytest.mark.parametrize("chunk", [4, 0], ids=["chunked", "oneshot"])
+def test_prefill_call_counters(tiny_elite_cfg, tiny_elite_model, chunk):
+    """Every prefill call counts once, with the token rows it ran at:
+    lanes x chunk for a chunked call, the padded bucket for a one-shot
+    call; live tokens never exceed them."""
+    params, buffers = tiny_elite_model
+    reg = MetricsRegistry()
+    scfg = _stream_scfg(prefill_chunk_tokens=chunk)
+    sched = serve_loop.Scheduler(params, buffers, tiny_elite_cfg, scfg,
+                                 metrics=reg)
+    reqs = _stream_reqs(tiny_elite_cfg)
+    rep = sched.run(reqs)
+    calls = reg.get("serve_prefill_calls_total").value
+    capacity = reg.get("serve_prefill_capacity_tokens_total").value
+    live = reg.get("serve_prefill_tokens_total").value
+    assert calls == rep.prefill_chunks > 0
+    if chunk:
+        assert capacity == calls * scfg.chunk_lanes * chunk
+    else:
+        assert capacity == sum(-(-len(r.prompt) // scfg.prefill_bucket)
+                               * scfg.prefill_bucket for r in reqs)
+    assert live == sum(len(r.prompt) for r in reqs) <= capacity
+
+
+def test_router_replicas_time_their_own_steps(tiny_elite_cfg,
+                                              tiny_elite_model):
+    """The router drives each replica's ``step()`` with no accounting of
+    its own; each replica's phase self times still sum to its step wall,
+    and the shared registry holds the fleet's totals."""
+    from repro.runtime.router import Router
+    params, buffers = tiny_elite_model
+    reg = MetricsRegistry()
+    router = Router(params, buffers, tiny_elite_cfg, _stream_scfg(),
+                    num_replicas=2, metrics=reg)
+    router.run(_stream_reqs(tiny_elite_cfg))
+    reps = [r.report(1.0) for r in router.replicas]
+    for rep in reps:
+        _assert_phases_sum_to_wall(rep.phase_ms, rep.step_wall_ms_total)
+    assert reg.get("serve_step_wall_ms_total").value == pytest.approx(
+        sum(rep.step_wall_ms_total for rep in reps))

@@ -13,9 +13,10 @@ Trace checks (Chrome trace-event JSON, the format serve.py --trace writes):
 * ``B``/``E`` events balance per (pid, tid) — every begin is closed by a
   matching end, never cross-nested
 * ``X`` spans on one (pid, tid) track nest properly — a span either
-  contains or is disjoint from every other span on its track (partial
-  overlap means the emitter timed overlapping phases, which would
-  double-count wall time)
+  contains or is disjoint from every other span on its track (a step
+  encloses its phases, and a ``swap`` phase may sit inside ``grow`` or
+  ``admit``; partial overlap means the emitter timed overlapping phases,
+  which would double-count wall time)
 * timestamps are non-negative and finite
 * prefix-cache instants carry well-formed args: ``prefix_hit`` needs
   positive numeric ``tokens``/``blocks``, ``prefix_miss`` numeric
