@@ -117,7 +117,7 @@ def kernel_cases(seed: int):
     f32 = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
     q_e, q_lat = f32(LANES, NH, R2), f32(LANES, NH, DC)
     qv_e, qv_lat = f32(LANES, W, NH, R2), f32(LANES, W, NH, DC)
-    k_e, c_k, c_v = (f32(n_blocks * BS, NKV, R2), f32(n_blocks * BS, DC),
+    k_e, c_k, c_v = (f32(n_blocks * BS, NKV * R2), f32(n_blocks * BS, DC),
                      f32(n_blocks * BS, DC))
     lengths = rng.integers(200, mb * BS - 63, LANES).astype(np.int32)
     bt = jnp.asarray(rng.permutation(n_blocks)[:LANES * mb]

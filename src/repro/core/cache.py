@@ -273,7 +273,11 @@ class PagedKVPool:
     Pages mirror ``lm.init_cache``'s per-``p_pos`` layout but replace the
     ``[B, max_len, ...]`` leading dims with one flat ``[n_slots, ...]`` token
     axis (``n_slots = num_blocks · block_size``); token ``t`` of block ``b``
-    lives at flat slot ``b · block_size + t``.  Only attention layers page —
+    lives at flat slot ``b · block_size + t``.  ``k_e`` also folds its
+    ``[nkv, 2r]`` tail into one ``nkv · 2r`` row (kv head ``h`` in columns
+    ``[h·2r, (h+1)·2r)``), so every stream is a 2-D ``[n_slots, width]``
+    array per layer whose ``[block, width]`` pages the kernels read without
+    a relayout.  Only attention layers page —
     serving currently requires an attention-only, EliteKV-enabled config
     (Mamba's O(1) state needs no paging; hybrid support is a ROADMAP item).
     """
@@ -315,7 +319,9 @@ class PagedKVPool:
         r2 = 2 * e.elite_r
 
         def _streams():
-            tails = {"k_e": (cfg.n_kv_heads, r2)}
+            # k_e rows are flat: kv head h owns columns [h·2r, (h+1)·2r), the
+            # lane-dense [block, nkv·2r] tile the paged kernels read
+            tails = {"k_e": (cfg.n_kv_heads * r2,)}
             if e.lrd == "joint":
                 tails["c"] = (e.d_ckv,)
             else:
@@ -336,10 +342,10 @@ class PagedKVPool:
 
         self.pages = {f"p{p}": _streams() for p in range(cfg.block_period)}
 
-        # Tensor-parallel page placement: the k_e stream shards its kv-head
-        # dim over the mesh's TP axis; the head-shared latent and the
-        # per-token scales replicate (distributed/sharding.py
-        # ``serving_page_pspecs``).  Block ids, chains, refcounts, prefix
+        # Tensor-parallel page placement: the k_e stream shards its row over
+        # the mesh's TP axis (each shard holds its kv heads' columns); the
+        # head-shared latent and the per-token scales replicate
+        # (distributed/sharding.py ``serving_page_pspecs``).  Block ids, chains, refcounts, prefix
         # hashes — everything host-side — stay shard-invariant: every device
         # holds the same slot layout, just a head slice of k_e, so COW / swap
         # / truncate / prefix sharing below never special-case the mesh.

@@ -244,7 +244,8 @@ def apply_decode(params, cfg, buffers, x, index, cache, use_kernel: bool = False
 def _scatter_pages(pages, k_e_new, c_k_new, c_v_new, slot_mapping):
     """Write per-token compressed streams into pool pages at flat slots.
     Out-of-range slots (the inactive-lane / prompt-padding sentinel) are
-    dropped.  k_e_new [N,nkv,2r], c_*_new [N,dc], slot_mapping [N].
+    dropped.  k_e_new [N,nkv,2r] lands as the pool's flat [N, nkv·2r] rows,
+    c_*_new [N,dc], slot_mapping [N].
 
     Quantized pool (``"k_e_scale" in pages``, see ``core/quant.py``): each
     token row is symmetric-absmax quantized to int8 *here, at write time* —
@@ -265,7 +266,7 @@ def _scatter_pages(pages, k_e_new, c_k_new, c_v_new, slot_mapping):
             new[name] = buf.at[slot_mapping].set(
                 val.astype(buf.dtype), mode="drop")
 
-    put("k_e", k_e_new)
+    put("k_e", k_e_new.reshape(k_e_new.shape[0], -1))
     if "c" in pages:
         put("c", c_k_new)
     else:
@@ -376,7 +377,7 @@ def _gather_prefix(pages, params, cfg, block_tables, block_size: int, dt):
         paged = stream.reshape((-1, block_size) + stream.shape[1:])
         return paged[block_tables].reshape((B, mb * block_size) + stream.shape[1:])
 
-    k_e_pre = gather(pages["k_e"]).astype(dt)                # [B,P,nkv,2r]
+    k_e_pre = gather(pages["k_e"]).astype(dt)                # [B,P,nkv·2r]
     c_k_pre, c_v_pre = _page_latents(pages)
     c_k_pre, c_v_pre = gather(c_k_pre).astype(dt), gather(c_v_pre).astype(dt)
     scales = _page_scales(pages)
@@ -384,9 +385,10 @@ def _gather_prefix(pages, params, cfg, block_tables, block_size: int, dt):
         # int8 pool: dequantize the gathered prefix rows before up-projecting
         # (one multiply by the per-slot scale — core/quant.py)
         ks, cks, cvs = (gather(s).astype(dt) for s in scales)    # [B,P] each
-        k_e_pre = k_e_pre * ks[..., None, None]
+        k_e_pre = k_e_pre * ks[..., None]
         c_k_pre = c_k_pre * cks[..., None]
         c_v_pre = c_v_pre * cvs[..., None]
+    k_e_pre = k_e_pre.reshape(B, mb * block_size, cfg.n_kv_heads, -1)
     k_ne_pre = jnp.einsum("bsc,che->bshe", c_k_pre, params["bk"].astype(dt))
     v_pre = jnp.einsum("bsc,che->bshe", c_v_pre, params["bv"].astype(dt))
     return jnp.concatenate([k_e_pre, k_ne_pre], axis=-1), v_pre

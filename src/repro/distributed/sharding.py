@@ -196,17 +196,19 @@ def opt_pspecs(opt_state, params, cfg: ModelConfig, plan: MeshPlan, moment_dtype
 def serving_page_pspecs(cfg: ModelConfig, plan: MeshPlan) -> Dict[str, P]:
     """PartitionSpecs for the paged serving pool's per-stream page arrays.
 
-    Per page the pool stores ``k_e [n_super, n_slots, nkv, 2r]`` plus the
+    Per page the pool stores ``k_e [n_super, n_slots, nkv·2r]`` plus the
     latent stream(s) ``c``/``c_k``/``c_v`` ``[n_super, n_slots, d_c]`` and,
     when quantized, per-token f32 ``*_scale [n_super, n_slots]`` arrays
-    (core/cache.py).  Only ``k_e`` has a head dim: it shards over the TP axis
-    when ``nkv`` divides, mirroring the ``wk_e``/``bk``/``bv`` head sharding
+    (core/cache.py).  Only ``k_e`` has heads: kv head ``h`` owns columns
+    ``[h·2r, (h+1)·2r)`` of its flat row, so sharding the row over the TP
+    axis gives each shard the contiguous columns of its kv heads, when
+    ``nkv`` divides — mirroring the ``wk_e``/``bk``/``bv`` head sharding
     in :func:`_spec_for`.  The latent is head-*shared* (J-LRD), and scales
     are per-token, so both replicate — which is exactly what lets block ids,
     prefix hashes, COW copies, swap and int8 scales stay shard-invariant.
     """
     head = plan.tp_axis if (plan.tp > 1 and cfg.n_kv_heads % plan.tp == 0) else None
-    specs: Dict[str, P] = {"k_e": P(None, None, head, None)}
+    specs: Dict[str, P] = {"k_e": P(None, None, head)}
     for name in ("c", "c_k", "c_v"):
         specs[name] = P()
     for name in ("k_e_scale", "c_scale", "c_k_scale", "c_v_scale"):

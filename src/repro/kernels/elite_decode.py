@@ -16,7 +16,8 @@ Layout (what the TPU lowering accepts): the grid is (B, cache blocks), and
 one step holds ALL heads of a lane against one block, so every block's last
 two dims are whole array dims and no step slices one kv head out of the
 second-minor axis.
-  - K_e blocks are [block, nkv·2r]: the pool's [slots, nkv, 2r] seen flat.
+  - K_e blocks are [block, nkv·2r]: the pool's rows, kv head h in columns
+    [h·2r, (h+1)·2r).
     The rotary query arrives block-diagonal, [nh, nkv·2r], with head n's 2r
     dims in the lanes of its kv head n // G and zeros elsewhere
     (``_block_diag_q_e``), so one dot scores every head's elite part.
@@ -58,15 +59,14 @@ def _block_diag_q_e(q_e, nkv: int):
 
 
 def _flat_pages(block_size: int, k_e_pages, c_k_pages, c_v_pages, *scales):
-    """Flat pool streams → per-block views (free reshapes): K_e
-    [n_blocks, block, nkv·2r], C_k/C_v [n_blocks, block, d_c], int8 scales
-    [n_blocks, 1, block]."""
+    """Pool streams [n_slots, width] → per-block views, a plain split of the
+    slot axis: K_e [n_blocks, block, nkv·2r], C_k/C_v [n_blocks, block, d_c],
+    int8 scales [n_blocks, 1, block]."""
     n_slots = k_e_pages.shape[0]
     assert n_slots % block_size == 0, (k_e_pages.shape, block_size)
     nb = n_slots // block_size
-    return (k_e_pages.reshape(nb, block_size, -1),
-            c_k_pages.reshape(nb, block_size, -1),
-            c_v_pages.reshape(nb, block_size, -1),
+    return (*(p.reshape(nb, block_size, -1)
+              for p in (k_e_pages, c_k_pages, c_v_pages)),
             *(s.reshape(nb, 1, block_size) for s in scales))
 
 
@@ -234,8 +234,12 @@ def _paged_call(kernel, name: str, scalars, q_e, q_lat, blocks,
 
 
 def _check_heads(q_e, k_e_pages, q_group: int) -> int:
-    nh, nkv = q_e.shape[-2], k_e_pages.shape[1]
-    assert nh == nkv * q_group, (nh, nkv, q_group)
+    """kv heads of a paged call, from the query heads: k_e_pages rows are
+    [nkv·2r] wide."""
+    nh, r2 = q_e.shape[-2:]
+    nkv = nh // q_group
+    assert nh == nkv * q_group, (nh, q_group)
+    assert k_e_pages.shape[1:] == (nkv * r2,), (k_e_pages.shape, nkv, r2)
     return nkv
 
 
@@ -254,7 +258,7 @@ def elite_decode_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
                        block_size: int, interpret: bool = False):
     """See kernels/ref.py::elite_decode_paged_ref for exact semantics.
 
-    q_e [B,nh,2r], q_lat [B,nh,d_c], k_e_pages [n_slots,nkv,2r],
+    q_e [B,nh,2r], q_lat [B,nh,d_c], k_e_pages [n_slots,nkv·2r],
     c_k/c_v_pages [n_slots,d_c], block_tables [B,mb] int32, lengths [B] int32
     →  o [B,nh,d_c].  Length-0 sequences (empty slots) produce zeros.
     """

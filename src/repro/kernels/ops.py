@@ -291,8 +291,8 @@ def rope_elite(x, positions, freqs, block_s: int = 1024):
 #
 # The paged kernels treat heads as *batch* dims of their grid — no reduction
 # ever crosses a head.  That makes head-sharding exact: each shard runs the
-# ordinary dispatch on its head slice (and its kv-head slice of the k_e
-# pages; the head-shared latent pages, per-token scales, block table and
+# ordinary dispatch on its head slice (and its kv heads' columns of the
+# k_e rows; the head-shared latent pages, per-token scales, block table and
 # lengths are replicated), producing per-head outputs bitwise identical to
 # the single-device call.  A tiled ``all_gather`` over the head axis then
 # replicates the full pre-epilogue output ``o [..., nh, d_c]`` so the
@@ -323,8 +323,9 @@ def elite_decode_paged_tp(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, scales,
                           block_size: int, mesh, tp_axis: str = "model",
                           force_xla: bool = False):
     """Tensor-parallel paged decode: ``q_e``/``q_lat [B, nh, *]`` sharded on
-    heads, ``k_e_pages [n_slots, nkv, 2r]`` sharded on kv heads, everything
-    else replicated; returns the replicated full-head ``o [B, nh, d_c]``.
+    heads, ``k_e_pages [n_slots, nkv·2r]`` sharded on its row (each shard
+    holds the contiguous columns of its kv heads), everything else
+    replicated; returns the replicated full-head ``o [B, nh, d_c]``.
 
     ``scales`` is ``None`` for an f32 pool or the
     ``(k_e_scale, c_k_scale, c_v_scale)`` triple for int8 — quantization is
@@ -342,7 +343,7 @@ def elite_decode_paged_tp(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, scales,
 
     heads = _P(None, tp_axis, None)
     args = [q_e, q_lat, k_e_pages, c_k_pages, c_v_pages]
-    specs = [heads, heads, _P(None, tp_axis, None),
+    specs = [heads, heads, _P(None, tp_axis),
              _rep(c_k_pages), _rep(c_v_pages)]
     if scales is not None:
         args += list(scales)
@@ -387,7 +388,7 @@ def elite_decode_sparse_paged_tp(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
 
     heads = _P(None, tp_axis, None)
     args = [q_e, q_lat, k_e_pages, c_k_pages, c_v_pages]
-    specs = [heads, heads, _P(None, tp_axis, None),
+    specs = [heads, heads, _P(None, tp_axis),
              _rep(c_k_pages), _rep(c_v_pages)]
     if scales is not None:
         args += list(scales)
@@ -431,7 +432,7 @@ def elite_verify_paged_tp(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, scales,
 
     heads = _P(None, None, tp_axis, None)
     args = [q_e, q_lat, k_e_pages, c_k_pages, c_v_pages]
-    specs = [heads, heads, _P(None, tp_axis, None),
+    specs = [heads, heads, _P(None, tp_axis),
              _rep(c_k_pages), _rep(c_v_pages)]
     if scales is not None:
         args += list(scales)

@@ -46,12 +46,19 @@ def elite_decode_ref(q_e, q_lat, k_e, c_k, c_v, lengths, q_group: int,
     return _decode_masked(q_e, q_lat, k_e, c_k, c_v, valid, q_group, scale)
 
 
+def _split_kv_heads(k_e_pages, q_e, q_group: int):
+    """Pool rows [n_slots, nkv·2r] → [n_slots, nkv, 2r]: kv head h holds
+    columns [h·2r, (h+1)·2r) of a row (nkv from the query heads)."""
+    return k_e_pages.reshape(k_e_pages.shape[0], q_e.shape[-2] // q_group, -1)
+
+
 def elite_decode_paged_ref(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
                            block_tables, lengths, q_group: int, scale: float,
                            block_size: int) -> jnp.ndarray:
     """Paged EliteKV decode attention: gather pages, then the dense oracle.
 
-    k_e_pages  [n_slots, nkv, 2r]   flat paged elite-key stream
+    k_e_pages  [n_slots, nkv·2r]    paged elite-key rows (kv head h in
+                                    columns [h·2r, (h+1)·2r))
     c_k_pages  [n_slots, dc]        flat paged latent stream (K side)
     c_v_pages  [n_slots, dc]        flat paged latent stream (V side)
     block_tables [B, max_blocks] int32   per-sequence block chains (pad = 0)
@@ -66,6 +73,7 @@ def elite_decode_paged_ref(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
         paged = pages.reshape((-1, block_size) + pages.shape[1:])
         return paged[block_tables].reshape((B, mb * block_size) + pages.shape[1:])
 
+    k_e_pages = _split_kv_heads(k_e_pages, q_e, q_group)
     return elite_decode_ref(q_e, q_lat, gather(k_e_pages), gather(c_k_pages),
                             gather(c_v_pages), lengths, q_group, scale)
 
@@ -136,6 +144,7 @@ def elite_decode_sparse_paged_ref(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
         return paged[sel_tables].reshape((B, W * block_size) + pages.shape[1:])
 
     valid = _sparse_valid(sel_counts, block_size)
+    k_e_pages = _split_kv_heads(k_e_pages, q_e, q_group)
     return _decode_masked(q_e, q_lat, gather(k_e_pages), gather(c_k_pages),
                           gather(c_v_pages), valid, q_group, scale)
 
@@ -189,6 +198,7 @@ def elite_verify_paged_ref(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
         paged = pages.reshape((-1, block_size) + pages.shape[1:])
         return paged[block_tables].reshape((B, mb * block_size) + pages.shape[1:])
 
+    k_e_pages = _split_kv_heads(k_e_pages, q_e, q_group)
     return elite_verify_ref(q_e, q_lat, gather(k_e_pages), gather(c_k_pages),
                             gather(c_v_pages), q_offsets, lengths, q_group,
                             scale)

@@ -121,7 +121,7 @@ def _run_parity():
     n_slots = nb * bs
     q_e = jnp.asarray(rng.standard_normal((B, nh, r2)), jnp.float32)
     q_lat = jnp.asarray(rng.standard_normal((B, nh, d_c)), jnp.float32)
-    K = jnp.asarray(rng.standard_normal((n_slots, nkv, r2)), jnp.float32)
+    K = jnp.asarray(rng.standard_normal((n_slots, nkv * r2)), jnp.float32)
     C = jnp.asarray(rng.standard_normal((n_slots, d_c)), jnp.float32)
     bt = jnp.asarray(rng.integers(0, nb, (B, 4)), jnp.int32)
     ln = jnp.asarray([5, 17, 30], jnp.int32)
@@ -146,7 +146,7 @@ def _run_parity():
         mesh=make_serving_mesh(tp=2), force_xla=True)
     out["verify_tp2"] = bool(jnp.all(gotv == refv))
 
-    Kq = jnp.asarray(rng.integers(-127, 127, (n_slots, nkv, r2)), jnp.int8)
+    Kq = jnp.asarray(rng.integers(-127, 127, (n_slots, nkv * r2)), jnp.int8)
     Cq = jnp.asarray(rng.integers(-127, 127, (n_slots, d_c)), jnp.int8)
     ks = jnp.asarray(rng.random((n_slots,)) + 0.1, jnp.float32)
     cs = jnp.asarray(rng.random((n_slots,)) + 0.1, jnp.float32)

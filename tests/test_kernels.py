@@ -146,7 +146,7 @@ def _verify_inputs(B, W, nkv, G, r2, dc, n_blocks, bs, seed=4):
     nh = nkv * G
     q_e = jax.random.normal(ks[0], (B, W, nh, r2))
     q_lat = jax.random.normal(ks[1], (B, W, nh, dc))
-    k_e_p = jax.random.normal(ks[2], (n_blocks * bs, nkv, r2))
+    k_e_p = jax.random.normal(ks[2], (n_blocks * bs, nkv * r2))
     c_p = jax.random.normal(ks[3], (n_blocks * bs, dc))
     # disjoint block chains in scrambled physical order
     perm = np.random.default_rng(seed).permutation(n_blocks)

@@ -28,7 +28,7 @@ def _paged_case(seed, B, nkv, G, r2, dc, bs, pool_blocks, mb, lengths):
     nh = nkv * G
     q_e = jax.random.normal(ks[0], (B, nh, r2))
     q_lat = jax.random.normal(ks[1], (B, nh, dc))
-    k_pages = jax.random.normal(ks[2], (pool_blocks * bs, nkv, r2))
+    k_pages = jax.random.normal(ks[2], (pool_blocks * bs, nkv * r2))
     c_pages = jax.random.normal(ks[3], (pool_blocks * bs, dc))
     # distinct random chains per sequence (disjoint, arbitrary order)
     perm = rng.permutation(pool_blocks)
@@ -107,7 +107,7 @@ def test_paged_matches_contiguous_kernel():
                               block_s=bs, interpret=True)
     # lay each sequence's cache out in its own pages, identity chains
     nb = S // bs
-    k_pages = k_e.reshape(B * S, nkv, r2)
+    k_pages = k_e.reshape(B * S, nkv * r2)
     c_pages = c.reshape(B * S, dc)
     bt = jnp.asarray([[b * nb + i for i in range(nb)] for b in range(B)],
                      jnp.int32)
@@ -294,4 +294,54 @@ def test_paged_prefill_writes_only_real_tokens(tiny_elite_cfg, tiny_elite_model)
     assert np.all(k_e[unowned] == 0.0)
     # the sp real tokens did land
     live = pool.slot_mapping([0] * sp, list(range(sp)))
-    assert np.all(np.abs(k_e[live]).max(axis=(1, 2)) > 0)
+    assert np.all(np.abs(k_e[live]).max(axis=1) > 0)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_pool_k_e_is_flat_rows(tiny_elite_cfg, tiny_elite_model, dtype):
+    """The pool keeps ``k_e`` as flat rows ``[n_super, n_slots, nkv·2r]``:
+    a written slot's row is that token's rotated elite key with kv head
+    ``h`` in columns ``[h·2r, (h+1)·2r)``.  An int8 pool stores exactly the
+    codes and scales of quantizing the token's ``[nkv, 2r]`` key."""
+    from repro.core import quant, rope as rope_lib
+    from repro.models.layers import rmsnorm
+    params, buffers = tiny_elite_model
+    cfg = tiny_elite_cfg
+    nkv, r2 = cfg.n_kv_heads, 2 * cfg.elitekv.elite_r
+    bs, sp = 4, 6
+
+    def prefill(pool_dtype):
+        pool = PagedKVPool(cfg, num_blocks=8, block_size=bs, dtype=pool_dtype)
+        assert pool.pages["p0"]["k_e"].shape == (
+            cfg.num_layers // cfg.block_period, 8 * bs, nkv * r2)
+        pool.ensure_capacity(0, sp)
+        tokens = (np.arange(sp, dtype=np.int32) * 7 + 3) % cfg.vocab_size
+        sm = pool.prefill_slot_mapping(0, 0, sp, sp)[None]
+        _, pages = lm.apply_prefill_paged(params, buffers, cfg,
+                                          {"tokens": jnp.asarray(tokens[None])},
+                                          pool.pages, jnp.asarray(sm))
+        return pages["p0"], sm[0], tokens
+
+    pages, slots, tokens = prefill("float32")
+    # layer 0's rotated elite keys, recomputed outside the pool
+    h = params["embed"]["table"][jnp.asarray(tokens)[None]].astype(cfg.dtype)
+    p0 = jax.tree.map(lambda t: t[0], params["blocks"]["p0"])
+    b0 = jax.tree.map(lambda t: t[0], buffers["blocks"]["p0"])
+    hn = rmsnorm(p0["attn_norm"], h, cfg.norm_eps)
+    k_e = jnp.einsum("bsd,dhe->bshe", hn, p0["attn"]["wk_e"])
+    k_e = np.asarray(rope_lib.apply_elite_rope(k_e, jnp.arange(sp),
+                                               b0["elite_freqs"]))[0]
+    rows = np.asarray(pages["k_e"][0])[slots]                 # [sp, nkv·2r]
+    for kv in range(nkv):
+        np.testing.assert_allclose(rows[:, kv * r2:(kv + 1) * r2], k_e[:, kv],
+                                   atol=1e-5)
+    if dtype == "int8":
+        q_pages, _, _ = prefill("int8")
+        # jitted, as in the pool write (eager division may round differently)
+        want_q, want_s = jax.jit(quant.quantize_rows)(
+            jnp.asarray(rows).reshape(sp, nkv, r2))
+        got_q = np.asarray(q_pages["k_e"][0])[slots]
+        got_s = np.asarray(q_pages["k_e_scale"][0])[slots]
+        assert got_q.dtype == np.int8
+        np.testing.assert_array_equal(got_q, np.asarray(want_q).reshape(sp, -1))
+        np.testing.assert_array_equal(got_s, np.asarray(want_s))

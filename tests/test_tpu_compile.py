@@ -61,7 +61,7 @@ def _paged_args(one_chip, kernel):
     n_slots = N_BLOCKS * BS
     q8 = kernel.endswith("_q8")
     pdt = jnp.int8 if q8 else jnp.float32
-    pages = [s((n_slots, NKV, R2), pdt), s((n_slots, DC), pdt),
+    pages = [s((n_slots, NKV * R2), pdt), s((n_slots, DC), pdt),
              s((n_slots, DC), pdt)]
     pages += [s((n_slots,))] * 3 if q8 else []
     if kernel.startswith("elite_verify"):
@@ -116,20 +116,11 @@ def test_rope_elite_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in step.lower(*args).compile().as_text()
 
 
-def test_full_width_decode_step_compiles_for_v5e(one_chip, monkeypatch):
-    """The serving decode step of full-width tinyllama_1_1b (22 layers, vocab
-    32000, f32) with a 1024×16-slot pool: the paged Pallas kernel is in the
-    program, and arguments + outputs + temporaries fit one chip's HBM."""
+def _compile_decode_step(one_chip, cfg):
+    """The serving decode step of ``cfg`` over a 1024×16-slot pool, compiled
+    for one described chip: ``(compiled, pages)``."""
     from repro.core.cache import PagedKVPool
-    from repro.kernels import ops
     from repro.models import lm
-
-    base = get_config("tinyllama_1_1b")
-    cfg = dataclasses.replace(base, elitekv=pick_dims(base, 0.25, align=16))
-    assert 2 * cfg.elitekv.elite_r == R2 and cfg.elitekv.d_ckv == DC
-    # the kernel dispatch asks the platform, and the platform here is the
-    # CPU: steer it to the Pallas path the chip takes
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
 
     def on_chip(tree):
         return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
@@ -149,8 +140,46 @@ def test_full_width_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     compiled = jax.jit(decode, donate_argnums=(3,)).lower(
         params, buffers, i32((B, 1)), pages, i32((B,)), i32((B, MB)),
         i32((B,))).compile()
+    return compiled, pages
+
+
+def test_full_width_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The serving decode step of full-width tinyllama_1_1b (22 layers, vocab
+    32000, f32) with a 1024×16-slot pool: the paged Pallas kernel is in the
+    program, and arguments + outputs + temporaries fit one chip's HBM."""
+    from repro.kernels import ops
+
+    base = get_config("tinyllama_1_1b")
+    cfg = dataclasses.replace(base, elitekv=pick_dims(base, 0.25, align=16))
+    assert 2 * cfg.elitekv.elite_r == R2 and cfg.elitekv.d_ckv == DC
+    # the kernel dispatch asks the platform, and the platform here is the
+    # CPU: steer it to the Pallas path the chip takes
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    compiled, _ = _compile_decode_step(one_chip, cfg)
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES, total
+
+
+def test_decode_step_keeps_128_wide_pool_layout(one_chip, monkeypatch):
+    """Where the pool's streams are 128 wide (Yi-6B's EliteKV widths: 4 kv
+    heads × 2r 32, d_ckv 128), no layer of the decode scan relays out its
+    pool slice: every copy over the slot axis moves a whole
+    [n_super, n_slots, 128] stack (the scan's carry), never a per-layer
+    [1, n_slots, ...] slice, so the step costs no pool-sized copy per layer."""
+    import re
+    from repro.configs.base import EliteKVConfig
+    from repro.kernels import ops
+
+    cfg = dataclasses.replace(
+        get_config("tinyllama_1_1b"), num_layers=4,
+        elitekv=EliteKVConfig(enabled=True, elite_r=16, d_ckv=128))
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    compiled, pages = _compile_decode_step(one_chip, cfg)
+    assert {a.shape for a in pages["p0"].values()} == {(4, N_BLOCKS * BS, 128)}
+    copies = [shape.split(",") for shape in re.findall(
+        r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", compiled.as_text())]
+    pool = [c for c in copies if str(N_BLOCKS * BS) in c]
+    assert pool and all(c[0] == "4" for c in pool), pool
