@@ -1,0 +1,2 @@
+"""step.prefill_fill.open: see ``servbench.readers.prefill_fill``."""
+from servbench.readers import prefill_fill as read  # noqa: F401

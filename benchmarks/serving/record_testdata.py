@@ -23,7 +23,8 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 sys.path.insert(1, str(HERE.parents[1] / "src"))
 
-CONF = dict(arch="granite_3_2b", hidden_size=512, intermediate_size=1024,
+CONF = dict(model="dense_elitekv",
+            arch="granite_3_2b", hidden_size=512, intermediate_size=1024,
             num_attention_heads=8, num_key_value_heads=2,
             num_hidden_layers=2, vocab_size=1024, rope_theta=10000.0,
             rms_norm_eps=1e-5, tie_word_embeddings=True,
@@ -60,20 +61,14 @@ def main() -> int:
     ap.add_argument("--out", required=True)
     ap.add_argument("--seconds", type=float, default=0.3)
     args = ap.parse_args()
-    from servbench import harness, xtrace
-    from servbench.weights import Dims
+    from servbench import harness, traffic, xtrace
     cell = harness.Cell(name="testdata", chips=1, conf=CONF, mix=MIX,
-                        dims=Dims.from_config(CONF), check={}, e2e=[],
-                        per_layer=[])
+                        check={}, e2e=[], per_layer=[])
     harness.device_info(1)
-    from repro.obs.metrics import MetricsRegistry
-    from repro.runtime.serve_loop import Scheduler
-    from servbench import traffic, weights
-    params, buffers = weights.make(cell.dims, 1)
-    sched = Scheduler(params, buffers, harness.model_config(CONF),
-                      harness.scheduler_config(CONF, MIX),
-                      metrics=MetricsRegistry())
-    server = harness.Server(cell, sched, traffic.Mix(MIX, cell.dims.vocab, 1))
+    params, buffers = cell.model.make(cell.dims, 1)
+    system = harness.build_system(cell, params, buffers)
+    server = harness.Server(cell, system, traffic.Mix(MIX, cell.dims.vocab,
+                                                      1))
     off = harness._annotator(False)
     initial = [server.send(p, b, c, harness.clock())
                for c, (p, b) in enumerate(server.mix.initial())]

@@ -8,6 +8,9 @@ its scheduled time; closed loop: when the client's previous request
 finished), and each token is stamped when the ``step`` that produced it
 returns.  From the scheduler it reads the phase counters of its metrics
 registry, the prefix cache's counters and the requests' public fields.
+Model code (sizes, weights, the plain reference, work counts) it reaches
+only through the configuration's model module
+(``servbench/models/<model>.py``).
 """
 from __future__ import annotations
 
@@ -24,8 +27,6 @@ import numpy as np
 
 from . import spec as speclib
 from . import traffic as trafficlib
-from . import work as worklib
-from .weights import Dims
 
 TRACE_SECONDS = 4.0            # the traced sub-window of a --trace 1 run
 clock = time.perf_counter
@@ -64,46 +65,30 @@ class CompileLog:
 
 @dataclasses.dataclass
 class Cell:
+    """One cell; its model module and sizes follow from ``conf["model"]``."""
     name: str
     chips: int
     conf: Dict[str, Any]
     mix: Dict[str, Any]
-    dims: Dims
     check: Dict[str, Any]
     e2e: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+
+    def __post_init__(self):
+        self.model = speclib.model(self.conf["model"])
+        self.dims = self.model.dims(self.conf)
 
     @classmethod
     def load(cls, name: str) -> "Cell":
         bench = speclib.benchmark()
         w = speclib.workload(bench, name)
-        conf = speclib.config(w["config"])
-        return cls(name=name, chips=w["chips"], conf=conf,
+        return cls(name=name, chips=w["chips"],
+                   conf=speclib.config(w["config"]),
                    mix=speclib.traffic(w["traffic"]),
-                   dims=Dims.from_config(conf),
                    check=speclib.load_json(speclib.HERE / "checks"
                                            / f"{name}.json"),
                    e2e=speclib.metrics_for(bench, "end_to_end", name),
                    per_layer=speclib.metrics_for(bench, "per_layer", name))
-
-
-def model_config(conf: Dict[str, Any]):
-    """The program's model configuration for a configuration file."""
-    from repro.configs.base import EliteKVConfig, ModelConfig
-    import jax.numpy as jnp
-    e = conf["elitekv"]
-    return ModelConfig(
-        name=conf["arch"], family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        dtype=jnp.dtype(conf["dtype"]),
-        elitekv=EliteKVConfig(enabled=True, elite_r=e["elite_r"],
-                              d_ckv=e["d_ckv"], lrd=e["lrd"]))
 
 
 def scheduler_config(conf: Dict[str, Any], mix: Dict[str, Any]):
@@ -118,6 +103,15 @@ def scheduler_config(conf: Dict[str, Any], mix: Dict[str, Any]):
         prefill_batch_lanes=s["prefill_batch_lanes"],
         prefix_cache=s["prefix_cache"], eviction=s["eviction"],
         cache_dtype=jnp.dtype(conf["pool_dtype"]))
+
+
+def build_scheduler(cell: Cell, params, buffers):
+    """The system under test: one ``Scheduler`` over the cell's weights."""
+    from repro.obs.metrics import MetricsRegistry
+    from repro.runtime.serve_loop import Scheduler
+    return Scheduler(params, buffers, cell.model.program_config(cell.conf),
+                     scheduler_config(cell.conf, cell.mix),
+                     metrics=MetricsRegistry())
 
 
 # -- bookkeeping of served requests --------------------------------------------
@@ -154,6 +148,8 @@ class Window:
     # pool blocks held (prefix-cache blocks kept for reuse included)
     blocks_peak: int = 0
     blocks_sum: int = 0
+    lanes0: int = 0                  # busy slots as the window opens
+    lanes1: int = 0                  # and as it closes
 
     def delta(self, key: str) -> float:
         return self.counters1.get(key, 0.0) - self.counters0.get(key, 0.0)
@@ -228,9 +224,12 @@ class Server:
             self._next = next(self._arrivals)
         return self._origin + self._next[0]
 
+    def lanes(self) -> int:
+        """Slots holding a request."""
+        return sum(s is not None for s in self.sched.slots)
+
     def busy(self) -> bool:
-        return bool(self.sched.waiting) or any(
-            s is not None for s in self.sched.slots)
+        return bool(self.sched.waiting) or self.lanes() > 0
 
     # -- one step --------------------------------------------------------------
     def step(self, annotate) -> None:
@@ -295,24 +294,24 @@ class Server:
     def _count_work(self, req, was) -> List[int]:
         """Model FLOPs of what ``req`` advanced by in the last step; returns
         the context lengths its decode tokens attended over."""
-        m = self.cell.dims
+        m, model = self.cell.dims, self.cell.model
         g0, pos0, hit0 = was if was is not None else (0, 0, 0)
         g1 = len(req.generated)
         hit = req.prefix_hit_tokens - hit0
         start = pos0 + hit
         n_pre = max(0, req.prefill_pos - start) if req.prefill_pos >= pos0 \
             else 0
-        self.window.model_flops += worklib.prefill_flops(m, start, n_pre)
+        self.window.model_flops += model.prefill_flops(m, start, n_pre)
         finished_prefill = n_pre > 0 and req.prefill_pos >= len(
             req.prefill_source())
         n_dec = g1 - g0 - (1 if finished_prefill else 0)
         if finished_prefill:
-            self.window.model_flops += worklib.head_flops(m)
+            self.window.model_flops += model.head_flops(m)
         ctx = []
         for k in range(n_dec):
             n = len(req.prompt) + g1 - n_dec + k
             ctx.append(n)
-            self.window.model_flops += worklib.decode_token_flops(m, n)
+            self.window.model_flops += model.decode_token_flops(m, n)
         return ctx
 
     # -- phases of the run -----------------------------------------------------
@@ -327,6 +326,7 @@ class Server:
         """Serve the mix for ``seconds``; the window closes with the step
         that runs past its end."""
         w = self.window = Window()
+        w.lanes0 = self.lanes()
         w.counters0 = self.counters()
         w.start = clock()
         if not self.closed and self._origin == 0.0:
@@ -344,6 +344,7 @@ class Server:
                 continue
             self.step(annotate)
         w.end = clock()
+        w.lanes1 = self.lanes()
         w.counters1 = self.counters()
         self.window = None
         return w
@@ -355,7 +356,13 @@ class Server:
                   "other"):
             inst = reg.get(f"serve_phase_{p}_ms_total")
             out[f"phase_{p}_ms"] = inst.value if inst is not None else 0.0
-        out["prefill_tokens"] = reg.get("serve_prefill_tokens_total").value
+        for key, name in (("prefill_tokens", "serve_prefill_tokens_total"),
+                          ("prefill_calls", "serve_prefill_calls_total"),
+                          ("prefill_capacity_tokens",
+                           "serve_prefill_capacity_tokens_total"),
+                          ("steps", "serve_steps_total"),
+                          ("step_wall_ms", "serve_step_wall_ms_total")):
+            out[key] = float(reg.get(name).value)
         out["decode_steps"] = float(reg.get("serve_step_ms").count)
         pc = self.sched.bm.prefix
         out["hit_tokens"] = float(pc.hit_tokens) if pc is not None else 0.0
@@ -387,11 +394,13 @@ def end_to_end(w: Window) -> Dict[str, Optional[float]]:
 @dataclasses.dataclass
 class Ctx:
     """What a per-layer metric's reader may read."""
-    dims: Dims
-    peaks: Dict[str, Any]
+    dims: Any                            # the model module's sizes
+    peaks: Dict[str, Any]                # one chip's
     window: Window                       # the untraced measured window
     traced: Optional[Window] = None      # the traced sub-window's host side
     trace: Any = None                    # xtrace.Reduced of the sub-window
+    model: Any = None                    # the model module
+    chips: int = 1                       # chips the cell's work runs on
 
 
 def _annotator(on: bool):
@@ -462,9 +471,6 @@ def setup_and_serve(cell: Cell, seed: int, seconds: float, trace: bool,
     program's device state is freed when it returns."""
     import jax
     from repro.launch import compile_cache
-    from repro.obs.metrics import MetricsRegistry
-    from repro.runtime.serve_loop import Scheduler
-    from . import weights
 
     parts = {"interpreter_and_jax_init_s": clock() - t_process}
     cache_dir = compile_cache.enable()
@@ -472,15 +478,13 @@ def setup_and_serve(cell: Cell, seed: int, seconds: float, trace: bool,
     log(f"compile cache: {cache_dir}")
 
     t = clock()
-    params, buffers = weights.make(cell.dims, seed,
-                                   jax.numpy.dtype(cell.conf["dtype"]))
+    params, buffers = cell.model.make(cell.dims, seed,
+                                      jax.numpy.dtype(cell.conf["dtype"]))
     jax.block_until_ready((params, buffers))
     parts["weights_s"] = clock() - t
 
     t = clock()
-    sched = Scheduler(params, buffers, model_config(cell.conf),
-                      scheduler_config(cell.conf, cell.mix),
-                      metrics=MetricsRegistry())
+    sched = build_scheduler(cell, params, buffers)
     del params, buffers
     jax.block_until_ready(sched.pool.pages)
     parts["pool_s"] = clock() - t
@@ -511,6 +515,8 @@ def setup_and_serve(cell: Cell, seed: int, seconds: float, trace: bool,
     log(f"window: {w.seconds} s, {w.steps} steps, {w.tokens} tokens, "
         f"{len(w.due)} requests due, {len(w.finished)} finished, "
         f"{compiles} compilations inside the window")
+    log(f"busy slots: {w.lanes0} as the window opened, {w.lanes1} as it "
+        f"closed")
     log(f"pool: {w.blocks_sum / max(w.steps, 1)} blocks held on average, "
         f"{w.blocks_peak} at most, of {cell.conf['num_blocks']}")
 
@@ -554,18 +560,17 @@ def traced_window(server: Server, seconds: float = TRACE_SECONDS,
 
 
 def check_served(cell: Cell, seed: int, checked,
-                 gaps_fn: Optional[Callable] = None) -> Dict[str, Any]:
+                 control: bool = False) -> Dict[str, Any]:
     """Reference logits over the checked requests: the widest gap by which a
-    served token's logit lies below the reference's best.  ``gaps_fn``
-    replaces the judge's reading (``reference.served_gaps``) with another
-    over the same requests, as the control does."""
-    from . import reference, weights
+    served token's logit lies below the reference's best.  ``control``
+    judges instead the token the float8-operand forward puts first at each
+    row, as the control does."""
     if not checked:
         return {"max_logit_gap": None, "tokens": 0, "requests": 0}
-    params, buffers = weights.make(cell.dims, seed)
-    gaps = (gaps_fn or reference.served_gaps)(
+    params, buffers = cell.model.make(cell.dims, seed)
+    gaps = cell.model.served_gaps(
         cell.dims, params, buffers, checked,
-        cell.mix["scheduler"]["max_len"])
+        cell.mix["scheduler"]["max_len"], control=control)
     del params, buffers
     allg = np.concatenate(gaps)
     return {"max_logit_gap": float(allg.max()), "tokens": int(allg.size),
@@ -606,7 +611,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     else:
         peaks = speclib.peaks(device["kind"])
         ctx = Ctx(dims=cell.dims, peaks=peaks, window=w,
-                  traced=out["traced"], trace=out["reduced"])
+                  traced=out["traced"], trace=out["reduced"],
+                  model=cell.model, chips=cell.chips)
         metrics = {}
         for m in cell.per_layer:
             v = speclib.reader(m["name"])(ctx)

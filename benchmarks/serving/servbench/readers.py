@@ -42,21 +42,29 @@ def prefill_ms_per_ktok(ctx) -> Optional[float]:
     return w.delta("phase_prefill_ms") / (toks / 1e3) if toks else None
 
 
+def prefill_fill(ctx) -> Optional[float]:
+    """Share of the prefill calls' token rows that held prompt tokens, the
+    rest being padding, %."""
+    w = ctx.window
+    rows = w.delta("prefill_capacity_tokens")
+    return 100.0 * w.delta("prefill_tokens") / rows if rows else None
+
+
 def decode_attn_roofline(ctx) -> Optional[float]:
     """The decode-attention kernel's share of its roofline in the traced
-    sub-window: least time for the algorithm's operations and bytes over
-    the kernel's device time, %."""
+    sub-window: least time for the algorithm's operations and bytes (the
+    model module's count, on one chip's peaks) over the kernel's device
+    time summed over the chips, %."""
     if ctx.trace is None or ctx.traced is None:
         return None
-    kernel_s = ctx.trace.op_seconds(DECODE_KERNEL)
+    kernel_s = ctx.trace.op_seconds(DECODE_KERNEL) * ctx.chips
     if kernel_s <= 0:
         return None
-    m = ctx.dims
     least = 0.0
     for lengths in ctx.traced.decode_lengths:
         if lengths:
-            f, b = work.decode_attn_call(m, lengths)
-            least += m.layers * work.roofline_seconds(f, b, ctx.peaks)
+            f, b = ctx.model.decode_attn_work(ctx.dims, lengths)
+            least += work.roofline_seconds(f, b, ctx.peaks)
     return 100.0 * least / kernel_s
 
 
@@ -68,10 +76,11 @@ def idle_share(ctx) -> Optional[float]:
 
 
 def mfu(ctx) -> Optional[float]:
-    """Model FLOP/s of the traced sub-window's work over the chip's peak, %."""
+    """Model FLOP/s of the traced sub-window's work over the peak of the
+    cell's chips, %."""
     if ctx.trace is None or ctx.traced is None:
         return None
     if ctx.traced.model_flops <= 0:
         return None
     return 100.0 * ctx.traced.model_flops / (
-        ctx.trace.window_s * ctx.peaks["flops_per_s"])
+        ctx.trace.window_s * ctx.chips * ctx.peaks["flops_per_s"])

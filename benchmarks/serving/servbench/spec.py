@@ -4,7 +4,10 @@
 and traffic mix, and each metric.  Everything that belongs to one of them is
 a file of its own under ``benchmarks/serving``:
 
-* ``configs/<config>.json``      model sizes, cache ratio, pool;
+* ``configs/<config>.json``      model sizes, cache ratio, pool, and the
+                                 ``"model"`` module that builds it;
+* ``servbench/models/<model>.py`` one module per architecture (its contract
+                                 is in ``servbench/models/__init__.py``);
 * ``traffic/<traffic>.json``     loop, lengths, sharing, scheduler settings;
 * ``checks/<cell>.json``         the cell's correctness limit and sample;
 * ``layer_metrics/<name>.py``    one reader per per-layer metric;
@@ -14,13 +17,16 @@ Adding a cell adds files and entries; no existing file changes.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
+import re
 from pathlib import Path
 from typing import Any, Dict, List
 
 HERE = Path(__file__).resolve().parents[1]          # benchmarks/serving
 ROOT = HERE.parents[1]                              # the checkout
+MODULE_NAME = re.compile(r"^[A-Za-z_][A-Za-z0-9_]*$")
 
 
 def load_json(path: Path) -> Any:
@@ -69,3 +75,10 @@ def reader(metric: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def model(name: str):
+    """The model module ``servbench/models/<name>.py``."""
+    if not MODULE_NAME.match(name):
+        raise ValueError(f"model module name {name!r} is not an identifier")
+    return importlib.import_module(f"servbench.models.{name}")

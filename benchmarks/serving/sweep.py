@@ -36,17 +36,13 @@ def main(argv=None) -> int:
 
     import numpy as np
     from repro.launch import compile_cache
-    from repro.obs.metrics import MetricsRegistry
-    from repro.runtime.serve_loop import Scheduler
-    from servbench import harness, traffic, weights
+    from servbench import harness, traffic
 
     cell = harness.Cell.load(args.workload)
     harness.device_info(cell.chips)
     compile_cache.enable()
-    params, buffers = weights.make(cell.dims, args.seed)
-    sched = Scheduler(params, buffers, harness.model_config(cell.conf),
-                      harness.scheduler_config(cell.conf, cell.mix),
-                      metrics=MetricsRegistry())
+    params, buffers = cell.model.make(cell.dims, args.seed)
+    sched = harness.build_scheduler(cell, params, buffers)
     del params, buffers
     off = harness._annotator(False)
     prev = None
@@ -65,7 +61,7 @@ def main(argv=None) -> int:
         for _ in range(4):
             windows.append(server.serve(args.seconds / 4, off))
             queue.append(len(sched.waiting))
-            busy.append(sum(s is not None for s in sched.slots))
+            busy.append(server.lanes())
         ttft = [[r.first - r.due for r in w.due if r.first is not None]
                 for w in windows]
         half = [ttft[0] + ttft[1], ttft[2] + ttft[3]]

@@ -14,10 +14,10 @@ import pytest
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
-from servbench import harness, reference, spec  # noqa: E402
-from servbench.weights import Dims  # noqa: E402
+from servbench import harness, spec  # noqa: E402
 
-CONF = dict(arch="tiny", hidden_size=128, intermediate_size=256,
+CONF = dict(model="dense_elitekv",
+            arch="tiny", hidden_size=128, intermediate_size=256,
             num_attention_heads=4, num_key_value_heads=2,
             num_hidden_layers=2, vocab_size=512, rope_theta=10000.0,
             rms_norm_eps=1e-5, tie_word_embeddings=False,
@@ -38,7 +38,6 @@ def _cell():
                             prefill_chunk_tokens=32, prefill_batch_lanes=2)
     bench = spec.benchmark()
     return harness.Cell(name="tiny", chips=1, conf=CONF, mix=mix,
-                        dims=Dims.from_config(CONF),
                         check={"max_logit_gap": LIMIT, "min_tokens": 128,
                                "max_requests": 6},
                         e2e=spec.metrics_for(bench, "end_to_end",
@@ -106,7 +105,7 @@ def test_fp8_control_fails_the_limit():
     rng = np.random.default_rng(3)
     reqs = [(rng.integers(0, m.vocab, n).astype(np.int32),
              list(rng.integers(0, m.vocab, 60))) for n in (30, 90, 150)]
-    chk = harness.check_served(cell, 5, reqs, reference.control_gaps)
+    chk = harness.check_served(cell, 5, reqs, control=True)
     assert chk["max_logit_gap"] > 3 * LIMIT
     assert harness.is_correct(chk, LIMIT) is False
 

@@ -14,7 +14,7 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
 from servbench import spec, traffic, work  # noqa: E402
-from servbench.weights import Dims, param_count  # noqa: E402
+from servbench.models import dense_elitekv as dense  # noqa: E402
 
 BENCH = spec.benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -221,41 +221,43 @@ def test_outputs_fit_max_len():
 # -- counting work ---------------------------------------------------------------
 
 def _dims(name):
-    return Dims.from_config(spec.config(name))
+    return dense.dims(spec.config(name))
 
 
 def test_work_counts_yi():
     m = _dims("yi6b_ekv25_l8")
-    # one lane at 3000 tokens: scores over 2r=32 + 128 latent dims and a
-    # 128-wide output, 32 heads; cache rows of 4·32 + 128 floats
-    f, b = work.decode_attn_call(m, [3000])
-    assert f == 2 * 32 * 3000 * (32 + 128) + 2 * 32 * 3000 * 128
-    assert b == 3000 * 256 * 4 + 32 * 160 * 4 + 32 * 128 * 4
-    assert work.decode_attn_call(m, [0, 0]) == (0.0, 0.0)
+    # one lane at 3000 tokens in each of the 8 layers: scores over 2r=32 +
+    # 128 latent dims and a 128-wide output, 32 heads; cache rows of 4·32 +
+    # 128 floats
+    f, b = dense.decode_attn_work(m, [3000])
+    assert f == 8 * (2 * 32 * 3000 * (32 + 128) + 2 * 32 * 3000 * 128)
+    assert b == 8 * (3000 * 256 * 4 + 32 * 160 * 4 + 32 * 128 * 4)
+    assert dense.decode_attn_work(m, [0, 0]) == (0.0, 0.0)
     # weights per layer: wq 4096·4096, wk_e 4096·128, a_kv 4096·128,
     # bk 128·4·96, bv 128·4·128, wo 4096·4096, mlp 3·4096·11008, 2 norms
     per_layer = (4096 * 4096 * 2 + 4096 * 128 * 2 + 128 * 4 * 96
                  + 128 * 4 * 128 + 3 * 4096 * 11008 + 2 * 4096)
-    assert param_count(m) == 8 * per_layer + 2 * 64000 * 4096 + 4096
-    dense = 2 * (4096 * 4096 * 2 + 4096 * 128 * 2 + 3 * 4096 * 11008)
+    assert dense.param_count(m) == 8 * per_layer + 2 * 64000 * 4096 + 4096
+    proj = 2 * (4096 * 4096 * 2 + 4096 * 128 * 2 + 3 * 4096 * 11008)
     absorb = 2 * 32 * 96 * 128 + 2 * 32 * 128 * 128
     attn = 2 * 32 * 100 * 160 + 2 * 32 * 100 * 128
-    assert work.decode_token_flops(m, 100) == \
-        8 * (dense + absorb + attn) + 2 * 4096 * 64000
+    assert dense.decode_token_flops(m, 100) == \
+        8 * (proj + absorb + attn) + 2 * 4096 * 64000
 
 
 def test_work_counts_granite():
     m = _dims("granite2b_ekv25_l20")
-    f, b = work.decode_attn_call(m, [10, 20])
-    assert f == 30 * (2 * 32 * (16 + 128) + 2 * 32 * 128)
-    assert b == 30 * (8 * 16 + 128) * 4 + 2 * (32 * 144 + 32 * 128) * 4
+    f, b = dense.decode_attn_work(m, [10, 20])
+    assert f == 20 * 30 * (2 * 32 * (16 + 128) + 2 * 32 * 128)
+    assert b == 20 * (30 * (8 * 16 + 128) * 4
+                      + 2 * (32 * 144 + 32 * 128) * 4)
     up = 2 * 128 * 8 * 48 + 2 * 128 * 8 * 64
-    dense = 2 * (2048 * 2048 * 2 + 2048 * 128 * 2 + 3 * 2048 * 8192)
-    assert work.prefill_token_flops(m, 9) == \
-        20 * (dense + up + 4 * 32 * 64 * 10)
-    total = sum(work.prefill_token_flops(m, p) for p in range(300, 556))
-    assert work.prefill_flops(m, 300, 256) == pytest.approx(total, rel=1e-12)
-    assert work.head_flops(m) == 2 * 2048 * 49155
+    proj = 2 * (2048 * 2048 * 2 + 2048 * 128 * 2 + 3 * 2048 * 8192)
+    assert dense.prefill_token_flops(m, 9) == \
+        20 * (proj + up + 4 * 32 * 64 * 10)
+    total = sum(dense.prefill_token_flops(m, p) for p in range(300, 556))
+    assert dense.prefill_flops(m, 300, 256) == pytest.approx(total, rel=1e-12)
+    assert dense.head_flops(m) == 2 * 2048 * 49155
     peaks = spec.peaks("TPU v5 lite")
     assert work.roofline_seconds(197e12, 1.0, peaks) == pytest.approx(1.0)
     assert work.roofline_seconds(1.0, 819e9, peaks) == pytest.approx(1.0)

@@ -12,10 +12,11 @@ import pytest
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
-from servbench import harness, reference, weights  # noqa: E402
-from servbench.weights import Dims  # noqa: E402
+from servbench import harness  # noqa: E402
+from servbench.models import dense_elitekv as dense  # noqa: E402
 
-CONF = dict(arch="tiny", hidden_size=64, intermediate_size=128,
+CONF = dict(model="dense_elitekv",
+            arch="tiny", hidden_size=64, intermediate_size=128,
             num_attention_heads=4, num_key_value_heads=2,
             num_hidden_layers=2, vocab_size=300, rope_theta=10000.0,
             rms_norm_eps=1e-5, tie_word_embeddings=False,
@@ -30,8 +31,8 @@ MIX = {"loop": "closed", "scheduler": dict(
 def _reference_logits(m, params, buffers, seq, max_len):
     pad = np.zeros((max_len,), np.int32)
     pad[:len(seq)] = seq
-    h = reference._hidden(m, False, params, buffers, jnp.asarray(pad))
-    w = reference._head(m, params)
+    h = dense.hidden(m, False, params, buffers, jnp.asarray(pad))
+    w = dense.head(m, params)
     return np.asarray(jnp.dot(h, w, precision="highest"))
 
 
@@ -39,9 +40,9 @@ def _reference_logits(m, params, buffers, seq, max_len):
 def test_scheduler_matches_reference(tied):
     from repro.runtime.serve_loop import Request, Scheduler
     conf = dict(CONF, tie_word_embeddings=tied)
-    m = Dims.from_config(conf)
-    params, buffers = weights.make(m, 12345)
-    sched = Scheduler(params, buffers, harness.model_config(conf),
+    m = dense.dims(conf)
+    params, buffers = dense.make(m, 12345)
+    sched = Scheduler(params, buffers, dense.program_config(conf),
                       harness.scheduler_config(conf, MIX))
     seen = []                    # (uid, position, logits row) per decode lane
     decode = sched._decode
@@ -80,7 +81,7 @@ def test_scheduler_matches_reference(tied):
     for uid, pos, row in seen:
         want = ref[uid][pos, :m.vocab]
         np.testing.assert_allclose(row[:m.vocab], want, atol=2e-4, rtol=0)
-    gaps = reference.served_gaps(m, params, buffers,
+    gaps = dense.served_gaps(m, params, buffers,
                                  [(r.prompt, r.generated) for r in reqs],
                                  MIX["scheduler"]["max_len"])
     assert max(g.max() for g in gaps) == 0.0
@@ -89,26 +90,26 @@ def test_scheduler_matches_reference(tied):
 def test_reference_is_causal_and_blocked():
     """Padding after the sequence changes no row, and the query blocks agree
     with one block over the whole sequence."""
-    m = Dims.from_config(CONF)
-    params, buffers = weights.make(m, 7)
+    m = dense.dims(CONF)
+    params, buffers = dense.make(m, 7)
     seq = np.random.default_rng(1).integers(0, m.vocab, 50).astype(np.int32)
     a = _reference_logits(m, params, buffers, seq, 64)[:50]
     b = _reference_logits(m, params, buffers, seq, 128)[:50]
     np.testing.assert_allclose(a, b, atol=1e-5)
-    old = reference.Q_BLOCK
+    old = dense.Q_BLOCK
     try:
-        reference.Q_BLOCK = 16               # a new shape: traced anew
+        dense.Q_BLOCK = 16                   # a new shape: traced anew
         c = _reference_logits(m, params, buffers, seq, 96)[:50]
     finally:
-        reference.Q_BLOCK = old
+        dense.Q_BLOCK = old
     np.testing.assert_allclose(a, c, atol=1e-5)
 
 
 def test_weights_deterministic_for_large_seeds():
-    m = Dims.from_config(CONF)
-    a, _ = weights.make(m, 2 ** 40 + 3)
-    b, _ = weights.make(m, 2 ** 40 + 3)
-    c, _ = weights.make(m, 3)
+    m = dense.dims(CONF)
+    a, _ = dense.make(m, 2 ** 40 + 3)
+    b, _ = dense.make(m, 2 ** 40 + 3)
+    c, _ = dense.make(m, 3)
     wa = np.asarray(a["blocks"]["p0"]["attn"]["wq"])
     assert np.array_equal(wa, np.asarray(b["blocks"]["p0"]["attn"]["wq"]))
     assert not np.array_equal(wa, np.asarray(c["blocks"]["p0"]["attn"]["wq"]))

@@ -10,10 +10,10 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
 from servbench import harness, readers, spans  # noqa: E402
-from servbench.weights import Dims  # noqa: E402
 from servbench.xtrace import Reduced  # noqa: E402
 
-CONF = dict(arch="tiny", hidden_size=64, intermediate_size=128,
+CONF = dict(model="dense_elitekv",
+            arch="tiny", hidden_size=64, intermediate_size=128,
             num_attention_heads=4, num_key_value_heads=2,
             num_hidden_layers=2, vocab_size=256, rope_theta=10000.0,
             rms_norm_eps=1e-5, tie_word_embeddings=False,
@@ -73,16 +73,11 @@ def test_step_host_ms_is_none_untraced():
 def traced_cpu():
     """A tiny model served on the CPU for a short traced sub-window, as a
     ``--trace 1`` run serves one: → (traced Window, Reduced)."""
-    from repro.obs.metrics import MetricsRegistry
-    from repro.runtime.serve_loop import Scheduler
-    from servbench import traffic, weights
-    cell = harness.Cell(name="tiny", chips=1, conf=CONF, mix=MIX,
-                        dims=Dims.from_config(CONF), check={}, e2e=[],
-                        per_layer=[])
-    params, buffers = weights.make(cell.dims, 3)
-    sched = Scheduler(params, buffers, harness.model_config(CONF),
-                      harness.scheduler_config(CONF, MIX),
-                      metrics=MetricsRegistry())
+    from servbench import traffic
+    cell = harness.Cell(name="tiny", chips=1, conf=CONF, mix=MIX, check={},
+                        e2e=[], per_layer=[])
+    params, buffers = cell.model.make(cell.dims, 3)
+    sched = harness.build_scheduler(cell, params, buffers)
     server = harness.Server(cell, sched, traffic.Mix(MIX, cell.dims.vocab, 3))
     off = harness._annotator(False)
     initial = [server.send(p, b, c, harness.clock())

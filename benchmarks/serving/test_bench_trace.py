@@ -1,6 +1,7 @@
-"""Trace reduction: busy and idle time, kernel time, idle gaps by host span;
-on hand-made timelines and on a small trace recorded on a TPU v5e
-(``testdata/small.xplane.pb``, written by ``record_testdata.py``)."""
+"""Trace reduction: busy and idle time, kernel time, idle gaps by host span,
+and the readers that divide by the cell's chips; on hand-made timelines and
+on a small trace recorded on a TPU v5e (``testdata/small.xplane.pb``,
+written by ``record_testdata.py``)."""
 import sys
 from pathlib import Path
 
@@ -9,7 +10,8 @@ import pytest
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
-from servbench import xtrace  # noqa: E402
+from servbench import harness, readers, xtrace  # noqa: E402
+from servbench.models import dense_elitekv as dense  # noqa: E402
 from servbench.xtrace import Op, Reduced  # noqa: E402
 
 SMALL = HERE / "testdata" / "small.xplane.pb"
@@ -49,6 +51,39 @@ def test_idle_gaps_named_by_innermost_host_span():
                                  "bench.record": 2.0,           # [4,6]
                                  "bench.step": 3.0})            # [7,10]
     assert r.idle_by_host(1) == [["bench.step", 3.0]]
+
+
+def test_shares_of_a_peak_divide_by_the_chips():
+    """MFU and the kernel's roofline share of a cell on N chips are over N
+    chips' peaks: the same work and trace read 1/N of one chip's share."""
+    m = dense.dims(dense.TINY)
+    traced = harness.Window(model_flops=2e12, decode_lengths=[[100, 200]])
+    peaks = {"flops_per_s": 1e14, "bytes_per_s": 1e11}
+
+    def ctx(chips):
+        return harness.Ctx(dims=m, peaks=peaks, window=harness.Window(),
+                           traced=traced, trace=_reduced(), model=dense,
+                           chips=chips)
+
+    assert readers.mfu(ctx(1)) == pytest.approx(100 * 2e12 / (10 * 1e14))
+    assert readers.mfu(ctx(2)) == pytest.approx(readers.mfu(ctx(1)) / 2)
+    f, b = dense.decode_attn_work(m, [100, 200])
+    least = max(f / 1e14, b / 1e11)
+    assert readers.decode_attn_roofline(ctx(1)) == pytest.approx(
+        100 * least / 6.0)                   # the kernel's mean of 2 + 10 s
+    assert readers.decode_attn_roofline(ctx(2)) == pytest.approx(
+        100 * least / 12.0)                  # its sum over both chips
+
+
+def test_prefill_fill_reads_the_counters():
+    w = harness.Window(counters0={"prefill_tokens": 100.0,
+                                  "prefill_capacity_tokens": 2048.0},
+                       counters1={"prefill_tokens": 612.0,
+                                  "prefill_capacity_tokens": 6144.0})
+    ctx = harness.Ctx(dims=None, peaks={}, window=w)
+    assert readers.prefill_fill(ctx) == pytest.approx(12.5)
+    assert readers.prefill_fill(harness.Ctx(
+        dims=None, peaks={}, window=harness.Window())) is None
 
 
 def test_no_device_ops_is_all_idle():
