@@ -318,18 +318,21 @@ def _update_block_summaries(pages, key, slot_mapping):
 
 
 def _page_latents(pages):
+    """``(c_k, c_v)`` pool streams; ``c_v`` is ``None`` for J-LRD's joint
+    ``c``, which the paged kernels then fetch once for both roles."""
     if "c" in pages:
-        return pages["c"], pages["c"]
+        return pages["c"], None
     return pages["c_k"], pages["c_v"]
 
 
 def _page_scales(pages):
     """Per-slot quantization scales ``(k_e, c_k, c_v)`` — None for an
-    unquantized (f32) pool.  J-LRD shares one latent scale for both roles."""
+    unquantized (f32) pool.  J-LRD shares one latent scale for both roles
+    (``c_v`` scale ``None``, as in ``_page_latents``)."""
     if "k_e_scale" not in pages:
         return None
     if "c" in pages:
-        return pages["k_e_scale"], pages["c_scale"], pages["c_scale"]
+        return pages["k_e_scale"], pages["c_scale"], None
     return pages["k_e_scale"], pages["c_k_scale"], pages["c_v_scale"]
 
 
@@ -379,12 +382,15 @@ def _gather_prefix(pages, params, cfg, block_tables, block_size: int, dt):
 
     k_e_pre = gather(pages["k_e"]).astype(dt)                # [B,P,nkv·2r]
     c_k_pre, c_v_pre = _page_latents(pages)
+    c_v_pre = c_k_pre if c_v_pre is None else c_v_pre
     c_k_pre, c_v_pre = gather(c_k_pre).astype(dt), gather(c_v_pre).astype(dt)
     scales = _page_scales(pages)
     if scales is not None:
         # int8 pool: dequantize the gathered prefix rows before up-projecting
         # (one multiply by the per-slot scale — core/quant.py)
-        ks, cks, cvs = (gather(s).astype(dt) for s in scales)    # [B,P] each
+        ks, cks, cvs = scales
+        cvs = cks if cvs is None else cvs
+        ks, cks, cvs = (gather(s).astype(dt) for s in (ks, cks, cvs))  # [B,P]
         k_e_pre = k_e_pre * ks[..., None]
         c_k_pre = c_k_pre * cks[..., None]
         c_v_pre = c_v_pre * cvs[..., None]
@@ -643,7 +649,7 @@ def apply_decode_paged(params, cfg, buffers, x, pages, slot_mapping,
             q_e.reshape(B, nh, -1), q_lat.reshape(B, nh, -1), K_e, C_k, C_v,
             *scales, block_tables, lengths, q_group=G, scale=dh ** -0.5,
             block_size=block_size, force_xla=not use_kernel)
-    o = o.reshape(B, 1, nh, C_v.shape[-1]).astype(dt)
+    o = o.reshape(B, 1, nh, C_k.shape[-1]).astype(dt)
 
     bv_q = rope_lib.expand_kv_to_q(jnp.moveaxis(params["bv"], 1, 0), G)
     o_heads = jnp.einsum("bqhc,hcd->bqhd", o, bv_q.astype(dt))

@@ -12,23 +12,32 @@ once".  Because this kernel reads only the 2r·n_kv + d_ckv compressed stream
 paper's compression ratio — and fusing both score paths means the latent C is
 read once and serves s_lat *and* the output GEMM.
 
-Layout (what the TPU lowering accepts): the grid is (B, cache blocks), and
-one step holds ALL heads of a lane against one block, so every block's last
-two dims are whole array dims and no step slices one kv head out of the
-second-minor axis.
-  - K_e blocks are [block, nkv·2r]: the pool's rows, kv head h in columns
-    [h·2r, (h+1)·2r).
-    The rotary query arrives block-diagonal, [nh, nkv·2r], with head n's 2r
+Layout (what the TPU lowering accepts).  Every step holds ALL heads of a
+lane, so no step slices one kv head out of the second-minor axis.
+  - K_e rows are [nkv·2r]: kv head h in columns [h·2r, (h+1)·2r).  The
+    rotary query arrives block-diagonal, [nh, nkv·2r], with head n's 2r
     dims in the lanes of its kv head n // G and zeros elsewhere
     (``_block_diag_q_e``), so one dot scores every head's elite part.
-  - C_k / C_v blocks are [block, d_c], read once per lane and block and
-    shared by all nh heads (the latent is head-shared).
-  - int8 scales are viewed as [n_blocks, 1, block]: they scale the score
-    columns and the softmax weights along lanes, never a page's rows.
-Per step the working set is the lane's queries [rows, nkv·2r + d_c], one
-block [block, nkv·2r + 2·d_c] and the accumulators [rows, d_c] — tens of KiB
-at published widths.  Lengths, block tables and selections arrive via
-scalar prefetch (ragged serving batches).
+  - The latent rows are [d_c], head-shared.  Under J-LRD the pool holds one
+    joint stream ``c``; the paged kernels fetch each of its pages once and
+    use it for both the latent scores and the value product (``c_v_pages``
+    ``None``).  S-LRD's separate ``c_k``/``c_v`` pools stay two streams.
+The contiguous kernel's grid is (B, S blocks) with BlockSpec tiles.  The
+paged kernels share one grouped live-page walk (``_walk``), grid (B,): lane
+b walks only the ``cdiv(length, bs)`` pages it holds, ``P = 256 // bs``
+pages per step (``pages_per_step``, clipped to the table), each page one
+contiguous async copy from the pool into a double-buffered VMEM scratch,
+driven by the scalar-prefetched block table.  A step scores
+``[rows, P·bs]`` at once and runs one online-softmax update; the next
+group, or the next live lane's first group, is in flight meanwhile.  Pad
+table entries and lanes of length 0 are never fetched.  int8 scales arrive
+as one 128-lane row per block and become the group's ``[1, P·bs]`` row
+that scales score columns and softmax weights, never a page's rows.
+Streams narrower than 128 lanes are zero-padded per call (``_pad_lanes``).
+Per step the working set is the lane's queries [rows, nkv·2r + d_c], two
+groups of pages [2, P·bs, nkv·2r + d_c (+ d_c)] and the accumulators
+[rows, d_c]: under 1 MiB at published widths.  Lengths, tables and
+selections arrive via scalar prefetch (ragged serving batches).
 
 Final stage of the docs/architecture.md pipeline: the streams these kernels
 read are produced by RoPElite selection (core/ropelite.py) + J-LRD
@@ -58,18 +67,6 @@ def _block_diag_q_e(q_e, nkv: int):
     return q_bd.reshape(q_e.shape[:-2] + (nh, nkv * r2))
 
 
-def _flat_pages(block_size: int, k_e_pages, c_k_pages, c_v_pages, *scales):
-    """Pool streams [n_slots, width] → per-block views, a plain split of the
-    slot axis: K_e [n_blocks, block, nkv·2r], C_k/C_v [n_blocks, block, d_c],
-    int8 scales [n_blocks, 1, block]."""
-    n_slots = k_e_pages.shape[0]
-    assert n_slots % block_size == 0, (k_e_pages.shape, block_size)
-    nb = n_slots // block_size
-    return (*(p.reshape(nb, block_size, -1)
-              for p in (k_e_pages, c_k_pages, c_v_pages)),
-            *(s.reshape(nb, 1, block_size) for s in scales))
-
-
 def _nt(a, b):
     """a [m, k] · b [n, k]ᵀ → [m, n] f32."""
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
@@ -82,21 +79,20 @@ def _init(acc_ref, m_ref, l_ref):
     l_ref[...] = jnp.zeros_like(l_ref)
 
 
-def _scores(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, scale: float):
-    """Scaled scores [rows, block] of the lane's query rows against one
-    block of an f32/bf16 pool."""
-    s = _nt(q_e_ref[0], k_e_ref[0])
-    s += _nt(q_lat_ref[0], c_k_ref[0])
+def _scores(q_e_ref, q_lat_ref, k_e, c_k, scale: float):
+    """Scaled scores [rows, n] of the lane's query rows against n cache
+    rows of an f32/bf16 pool (k_e [n, nkv·2r], c_k [n, d_c])."""
+    s = _nt(q_e_ref[0], k_e)
+    s += _nt(q_lat_ref[0], c_k)
     return s * scale
 
 
-def _scores_q8(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, k_s_ref, ck_s_ref,
-               scale: float):
-    """``_scores`` over int8 pages: dequantization is fused as a per-slot
-    scale on each score column (``q·(k·s) = (q·k)·s``) — the HBM read stays
-    one byte per element, the math stays f32."""
-    s = _nt(q_e_ref[0], k_e_ref[0].astype(jnp.float32)) * k_s_ref[0]
-    s += _nt(q_lat_ref[0], c_k_ref[0].astype(jnp.float32)) * ck_s_ref[0]
+def _scores_q8(q_e_ref, q_lat_ref, k_e, c_k, k_s, ck_s, scale: float):
+    """``_scores`` over int8 rows: dequantization is fused as a per-slot
+    scale on each score column (``q·(k·s) = (q·k)·s``, scales [1, n]) — the
+    HBM read stays one byte per element, the math stays f32."""
+    s = _nt(q_e_ref[0], k_e.astype(jnp.float32)) * k_s
+    s += _nt(q_lat_ref[0], c_k.astype(jnp.float32)) * ck_s
     return s * scale
 
 
@@ -136,7 +132,7 @@ def _kernel(lengths_ref,                  # scalar-prefetch [B] int32
 
     @pl.when(start < length)
     def _step():
-        s = _scores(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, scale)
+        s = _scores(q_e_ref, q_lat_ref, k_e_ref[0], c_k_ref[0], scale)
         pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         _accumulate(jnp.where(pos < length, s, NEG_INF), c_v_ref[0],
                     acc_ref, m_ref, l_ref)
@@ -191,46 +187,216 @@ def elite_decode(q_e, q_lat, k_e, c_k, c_v, lengths, q_group: int,
 # paged kernels: the cache lives in a block pool, sequences own block chains
 # ---------------------------------------------------------------------------
 
-def _paged_call(kernel, name: str, scalars, q_e, q_lat, blocks,
-                out_dtype, interpret: bool):
-    """The ``pallas_call`` every paged kernel shares.  Grid (B, walk): step
-    (b, s) holds lane b's query rows and pool block ``scalars[0][b, s]`` —
-    the first scalar-prefetch operand is the block table (or the sparse
-    selection table), so the index maps pull pages straight from the pool
-    and no gather ever materializes a sequence.  ``blocks`` are the
-    ``_flat_pages`` views; q_e [B, rows, nkv·2r] (block-diagonal), q_lat
-    [B, rows, d_c] → o [B, rows, d_c]."""
-    B, rows, K = q_e.shape
+_GROUP_TOKENS = 256         # tokens one step of the paged walk covers
+_LANES = 128                # a page copy moves whole rows of 128 lanes
+
+
+def pages_per_step(block_size: int, n_table: int) -> int:
+    """Pages ``P`` one step of the paged walk fetches: about 256 tokens,
+    ``max(1, 256 // block_size)``, clipped to the table's ``n_table``
+    entries.  Derived from shapes only; the scheduler's page counters use
+    the same rule."""
+    return max(1, min(_GROUP_TOKENS // block_size, n_table))
+
+
+def _pad_lanes(x):
+    """Zero-pad the last dim to a multiple of 128: a DMA moves whole
+    128-lane rows, so narrower streams (TinyLlama at 25%, TP shards) are
+    widened here, one copy per call; the zero lanes add exact zeros to
+    every dot."""
+    pad = -x.shape[-1] % _LANES
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def _scale_rows(scale, block_size: int):
+    """Per-slot int8 scales [n_slots] → one 128-lane row per block, the
+    block's scales tiled across it, so ``_scale_row`` needs only selects."""
+    assert _LANES % block_size == 0, block_size
+    return jnp.tile(scale.reshape(-1, block_size), (1, _LANES // block_size))
+
+
+def _scale_row(buf, P: int, bs: int):
+    """A group's fetched scale rows [P, 1, 128] → the page-major row
+    [1, P·bs] that scales its score columns: page i's scales sit in lanes
+    [i·bs, (i+1)·bs)."""
+    per = _LANES // bs
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1) // bs
+    chunks = []
+    for h in range(pl.cdiv(P, per)):
+        row = jnp.zeros((1, _LANES), jnp.float32)
+        for i in range(h * per, min(P, (h + 1) * per)):
+            row = jnp.where(lane == i - h * per, buf[i], row)
+        chunks.append(row)
+    row = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=1)
+    return row[:, :P * bs]
+
+
+def _group_rows(buf, P: int, bs: int):
+    """A group's fetched pages [P, bs, W] → their rows [P·bs, W] (int8
+    pages widened to f32 first; the f32 tiling accepts the merge)."""
+    x = buf[...]
+    if x.dtype == jnp.int8:
+        x = x.astype(jnp.float32)
+    return x.reshape(P * bs, x.shape[-1])
+
+
+def _walk(table_ref, n_pages_ref, after_ref, *refs, body, n_extra: int,
+          n_page_streams: int, roles, P: int, bs: int):
+    """The grouped live-page walk every paged kernel shares; grid (B,).
+
+    Lane b walks ``cdiv(n_pages[b], P)`` groups of ``P`` table entries.
+    Each group's pages are fetched from the pool (``memory_space=ANY``) by
+    one async copy per page and stream, straight from the scalar-prefetched
+    ``table`` — a page is ``bs`` consecutive pool rows, one contiguous DMA —
+    into a double-buffered VMEM scratch; entries at or past ``n_pages[b]``
+    are never fetched, so a lane of length 0 fetches nothing and writes
+    zeros.  While group g computes, group g+1 is in flight; the last group
+    of lane b prefetches the first group of the next live lane
+    (``after[b + 1]``), so lanes do not each start on a DMA bubble.
+
+    ``body(b, g, views, *extra_refs, q_e_ref, q_lat_ref, acc, m, l)``
+    runs one online-softmax update per group; ``views`` holds the group's
+    rows ``[P·bs, W]`` per page stream and the page-major row ``[1, P·bs]``
+    per scale stream, in the order ``roles`` names (a joint latent is one
+    stream serving two roles, fetched once)."""
+    ns = max(roles) + 1
+    extra = refs[:n_extra]
+    q_e_ref, q_lat_ref = refs[n_extra:n_extra + 2]
+    pools = refs[n_extra + 2:n_extra + 2 + ns]
+    o_ref = refs[n_extra + 2 + ns]
+    bufs = refs[n_extra + 3 + ns:n_extra + 3 + 2 * ns]
+    sem, slot_ref, acc_ref, m_ref, l_ref = refs[n_extra + 3 + 2 * ns:]
+    b = pl.program_id(0)
+    rows = [bs if k < n_page_streams else 1 for k in range(ns)]
+
+    def fetch(lane, g, slot, wait: bool):
+        n = n_pages_ref[lane]
+        for i in range(P):
+            @pl.when(g * P + i < n)
+            def _():
+                blk = table_ref[lane, g * P + i]
+                for pool, buf, r in zip(pools, bufs, rows):
+                    cp = pltpu.make_async_copy(pool.at[pl.ds(blk * r, r)],
+                                               buf.at[slot, i], sem.at[slot])
+                    if wait:
+                        cp.wait()
+                    else:
+                        cp.start()
+
+    @pl.when(b == 0)
+    def _():
+        # pages a last group leaves unfetched keep finite contents: their
+        # masked weights are exact zeros, and 0 · (uninitialized NaN) is not
+        slot_ref[0] = 0
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+
+    _init(acc_ref, m_ref, l_ref)
+    first = slot_ref[0]
+    n_groups = pl.cdiv(n_pages_ref[b], P)
+
+    @pl.when(after_ref[0] == b)                 # the first live lane
+    def _():
+        fetch(b, 0, first, wait=False)
+
+    def group(g, carry):
+        slot = (first + g) % 2
+        nxt = after_ref[b + 1]
+
+        @pl.when(g + 1 < n_groups)
+        def _():
+            fetch(b, g + 1, 1 - slot, wait=False)
+
+        @pl.when((g + 1 == n_groups) & (nxt < pl.num_programs(0)))
+        def _():
+            fetch(nxt, 0, 1 - slot, wait=False)
+
+        fetch(b, g, slot, wait=True)
+        views = [_group_rows(buf.at[slot], P, bs) if k < n_page_streams
+                 else _scale_row(buf.at[slot], P, bs)
+                 for k, buf in enumerate(bufs)]
+        body(b, g, [views[k] for k in roles], *extra, q_e_ref, q_lat_ref,
+             acc_ref, m_ref, l_ref)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    slot_ref[0] = (first + n_groups) % 2
+    _finish(o_ref, acc_ref, l_ref)
+
+
+def _streams(block_size: int, k_e_pages, c_k_pages, c_v_pages, scales=()):
+    """The distinct pool arrays a walk fetches and the stream serving each
+    role (k_e, c_k, c_v[, their scales]).  ``c_v_pages is None`` is the
+    joint latent: one ``c`` stream, fetched once, serves both roles (and
+    likewise a ``None`` c_v scale)."""
+    pages = [k_e_pages, c_k_pages] + ([] if c_v_pages is None else [c_v_pages])
+    roles = [0, 1, len(pages) - 1]
+    arrays = [_pad_lanes(p) for p in pages]
+    if scales:
+        k_s, ck_s, cv_s = scales
+        n = len(arrays)
+        arrays += [_scale_rows(s, block_size)
+                   for s in (k_s, ck_s, cv_s) if s is not None]
+        roles += [n, n + 1, len(arrays) - 1]
+    return arrays, len(pages), tuple(roles)
+
+
+def _paged_call(body, name: str, table, n_pages, extra, q_e, q_lat, pools,
+                block_size: int, out_dtype, interpret: bool):
+    """The ``pallas_call`` every paged kernel shares: ``_walk`` over
+    ``table [B, n_table]`` (the block table, or the sparse selection
+    table), lane b walking its first ``n_pages[b]`` entries.  ``extra``
+    are the body's scalar-prefetch operands; ``pools`` the ``_streams``
+    triple.  q_e [B, rows, nkv·2r] (block-diagonal), q_lat [B, rows, d_c]
+    → o [B, rows, d_c]."""
+    arrays, n_page_streams, roles = pools
+    B, rows, _ = q_e.shape
     d_c = q_lat.shape[-1]
-    block_size = blocks[0].shape[1]
+    P = pages_per_step(block_size, table.shape[1])
+    q_e, q_lat = _pad_lanes(q_e), _pad_lanes(q_lat)
+    K, D = q_e.shape[-1], q_lat.shape[-1]
+    # after[b]: the first lane >= b with pages to walk (B when none)
+    idx = jnp.arange(B, dtype=jnp.int32)
+    after = jax.lax.cummin(jnp.where(n_pages > 0, idx, B), reverse=True)
+    after = jnp.concatenate([after, jnp.full((1,), B, jnp.int32)])
 
     def lane(w):
-        return pl.BlockSpec((1, rows, w), lambda b, s, *_: (b, 0, 0))
+        return pl.BlockSpec((1, rows, w), lambda b, *_: (b, 0, 0))
 
-    def page(shape):
-        return pl.BlockSpec((1,) + shape,
-                            lambda b, s, tbl, *_: (tbl[b, s], 0, 0))
-
-    in_specs = [lane(K), lane(d_c), page((block_size, K)),
-                page((block_size, d_c)), page((block_size, d_c))]
-    in_specs += [page((1, block_size))] * (len(blocks) - 3)
-    return pl.pallas_call(
-        kernel,
+    bufs = [pltpu.VMEM((2, P, block_size if k < n_page_streams else 1,
+                        a.shape[1]), a.dtype) for k, a in enumerate(arrays)]
+    out = pl.pallas_call(
+        functools.partial(_walk, body=body, n_extra=len(extra),
+                          n_page_streams=n_page_streams, roles=roles, P=P,
+                          bs=block_size),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars),
-            grid=(B, scalars[0].shape[1]),
-            in_specs=in_specs,
-            out_specs=lane(d_c),
-            scratch_shapes=[
-                pltpu.VMEM((rows, d_c), jnp.float32),
+            num_scalar_prefetch=3 + len(extra),
+            grid=(B,),
+            in_specs=[lane(K), lane(D)]
+                     + [pl.BlockSpec(memory_space=pl.ANY)] * len(arrays),
+            out_specs=lane(D),
+            scratch_shapes=bufs + [
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((rows, D), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, rows, d_c), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((B, rows, D), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=name,
-    )(*scalars, q_e, q_lat, *blocks)
+    )(table, n_pages.astype(jnp.int32), after, *extra, q_e, q_lat, *arrays)
+    return out[..., :d_c]
+
+
+def _chain_pages(lengths, block_size: int, n_table: int):
+    """Table entries a lane of each length walks: ``cdiv(length, bs)``."""
+    return jnp.minimum(-(-lengths // block_size), n_table).astype(jnp.int32)
 
 
 def _check_heads(q_e, k_e_pages, q_group: int) -> int:
@@ -243,14 +409,20 @@ def _check_heads(q_e, k_e_pages, q_group: int) -> int:
     return nkv
 
 
-def _paged_kernel(block_tables_ref,           # scalar-prefetch [B, mb] int32
-                  *refs, block_size: int, scale: float, max_blocks: int):
-    """``_kernel`` with grid dim 1 walking the *block table* instead of a
-    contiguous S axis: only the BlockSpec index maps read the table, pulling
-    page ``block_tables[b, sb]`` straight from the pool, so no gather ever
-    materializes the sequence."""
-    del block_tables_ref
-    _kernel(*refs, block_s=block_size, scale=scale, n_blocks=max_blocks)
+def _positions(g, span: int, shape):
+    """Cache positions of a group's score columns."""
+    return g * span + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _paged_body(b, g, views, lengths_ref, q_e_ref, q_lat_ref,
+                acc_ref, m_ref, l_ref, *, scale: float, span: int):
+    """One group of the dense walk: scores against ``P`` pages at once,
+    masked by ``pos < length``."""
+    k_e, c_k, c_v = views
+    s = _scores(q_e_ref, q_lat_ref, k_e, c_k, scale)
+    pos = _positions(g, span, s.shape)
+    _accumulate(jnp.where(pos < lengths_ref[b], s, NEG_INF), c_v,
+                acc_ref, m_ref, l_ref)
 
 
 def elite_decode_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -259,56 +431,37 @@ def elite_decode_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     """See kernels/ref.py::elite_decode_paged_ref for exact semantics.
 
     q_e [B,nh,2r], q_lat [B,nh,d_c], k_e_pages [n_slots,nkv·2r],
-    c_k/c_v_pages [n_slots,d_c], block_tables [B,mb] int32, lengths [B] int32
-    →  o [B,nh,d_c].  Length-0 sequences (empty slots) produce zeros.
+    c_k/c_v_pages [n_slots,d_c] (``c_v_pages=None``: the joint latent,
+    c_k_pages serves both roles and is read once), block_tables [B,mb]
+    int32, lengths [B] int32 →  o [B,nh,d_c].  Length-0 sequences (empty
+    slots) produce zeros.
     """
     nkv = _check_heads(q_e, k_e_pages, q_group)
     B, mb = block_tables.shape
     assert q_e.shape[0] == B and lengths.shape == (B,)
+    span = pages_per_step(block_size, mb) * block_size
     return _paged_call(
-        functools.partial(_paged_kernel, block_size=block_size, scale=scale,
-                          max_blocks=mb),
-        "elite_decode_paged", (block_tables, lengths),
+        functools.partial(_paged_body, scale=scale, span=span),
+        "elite_decode_paged", block_tables,
+        _chain_pages(lengths, block_size, mb), (lengths,),
         _block_diag_q_e(q_e, nkv), q_lat,
-        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages),
-        c_v_pages.dtype, interpret)
+        _streams(block_size, k_e_pages, c_k_pages, c_v_pages), block_size,
+        c_k_pages.dtype, interpret)
 
 
 # ---------------------------------------------------------------------------
 # paged decode over an int8 pool: fused dequantization
 # ---------------------------------------------------------------------------
 
-def _paged_kernel_q8(block_tables_ref,        # scalar-prefetch [B, mb] int32
-                     lengths_ref,             # scalar-prefetch [B] int32
-                     q_e_ref, q_lat_ref, k_e_ref, c_k_ref, c_v_ref,
-                     k_s_ref, ck_s_ref, cv_s_ref,
-                     o_ref,
-                     acc_ref, m_ref, l_ref,
-                     *, block_size: int, scale: float, max_blocks: int):
-    """``_paged_kernel`` over int8 pages: the same block-table walk also
-    pulls each block's per-slot f32 scales (``_scores_q8``)."""
-    b = pl.program_id(0)
-    sb = pl.program_id(1)
-
-    @pl.when(sb == 0)
-    def _():
-        _init(acc_ref, m_ref, l_ref)
-
-    length = lengths_ref[b]
-    start = sb * block_size
-
-    @pl.when(start < length)
-    def _step():
-        s = _scores_q8(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, k_s_ref,
-                       ck_s_ref, scale)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _accumulate(jnp.where(pos < length, s, NEG_INF),
-                    c_v_ref[0].astype(jnp.float32), acc_ref, m_ref, l_ref,
-                    cv_s_ref[0])
-
-    @pl.when(sb == max_blocks - 1)
-    def _():
-        _finish(o_ref, acc_ref, l_ref)
+def _paged_body_q8(b, g, views, lengths_ref, q_e_ref, q_lat_ref,
+                   acc_ref, m_ref, l_ref, *, scale: float, span: int):
+    """``_paged_body`` over int8 pages: each group's per-slot f32 scales
+    scale its score columns and softmax weights (``_scores_q8``)."""
+    k_e, c_k, c_v, k_s, ck_s, cv_s = views
+    s = _scores_q8(q_e_ref, q_lat_ref, k_e, c_k, k_s, ck_s, scale)
+    pos = _positions(g, span, s.shape)
+    _accumulate(jnp.where(pos < lengths_ref[b], s, NEG_INF), c_v,
+                acc_ref, m_ref, l_ref, cv_s)
 
 
 def elite_decode_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -318,59 +471,63 @@ def elite_decode_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     """See kernels/ref.py::elite_decode_paged_q8_ref for exact semantics.
 
     Pages as in ``elite_decode_paged`` but int8; ``*_scale`` [n_slots] f32
-    per-slot quantization scales.  Output is always f32 (the int8 pages must
-    never leak their dtype into the attention output).
+    per-slot quantization scales (``c_v_pages``/``c_v_scale`` ``None``:
+    the joint latent and its scale, read once).  Output is always f32 (the
+    int8 pages must never leak their dtype into the attention output).
     """
     nkv = _check_heads(q_e, k_e_pages, q_group)
     B, mb = block_tables.shape
     assert q_e.shape[0] == B and lengths.shape == (B,)
+    span = pages_per_step(block_size, mb) * block_size
     return _paged_call(
-        functools.partial(_paged_kernel_q8, block_size=block_size,
-                          scale=scale, max_blocks=mb),
-        "elite_decode_paged_q8", (block_tables, lengths),
+        functools.partial(_paged_body_q8, scale=scale, span=span),
+        "elite_decode_paged_q8", block_tables,
+        _chain_pages(lengths, block_size, mb), (lengths,),
         _block_diag_q_e(q_e.astype(jnp.float32), nkv),
         q_lat.astype(jnp.float32),
-        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages,
-                    k_e_scale, c_k_scale, c_v_scale),
-        jnp.float32, interpret)
+        _streams(block_size, k_e_pages, c_k_pages, c_v_pages,
+                 (k_e_scale, c_k_scale, c_v_scale)),
+        block_size, jnp.float32, interpret)
 
 
 # ---------------------------------------------------------------------------
 # sparse paged decode: walk a top-k SELECTION of blocks, not the whole chain
 # ---------------------------------------------------------------------------
 
-def _sparse_kernel(sel_tables_ref,            # scalar-prefetch [B, W] int32
-                   sel_counts_ref,            # scalar-prefetch [B, W] int32
-                   q_e_ref, q_lat_ref, k_e_ref, c_k_ref, c_v_ref,
-                   o_ref,
-                   acc_ref, m_ref, l_ref,
-                   *, scale: float, num_sel: int):
-    """``_paged_kernel`` where grid dim 1 walks ``sel_tables`` — the top-k
-    block selection from ``ref.py::select_topk_blocks`` — instead of the full
-    block chain.  The length mask becomes a per-block row count
-    (``sel_counts[b, sb]``; 0 skips the block entirely), so the kernel does
-    O(k·block) work per token.  Selected blocks arrive in ascending chain
-    order; with the full chain selected the walk, mask, and accumulation
-    order equal the dense kernel's exactly (the bit-identity wall)."""
-    b = pl.program_id(0)
-    sb = pl.program_id(1)
+def _selected_pages(sel_counts):
+    """Selection entries a lane walks: through its last non-empty one
+    (``select_topk_blocks`` puts empty entries last)."""
+    W = sel_counts.shape[1]
+    pos = jnp.arange(1, W + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(sel_counts > 0, pos, 0), axis=1)
 
-    @pl.when(sb == 0)
-    def _():
-        _init(acc_ref, m_ref, l_ref)
 
-    count = sel_counts_ref[b, sb]
+def _count_mask(b, g, counts_ref, shape, P: int, bs: int):
+    """A sparse group's validity mask: column ``i·bs + t`` is valid iff
+    ``t < sel_counts[b, g·P + i]`` (entries past the table count 0).  For
+    the full chain this equals the dense ``pos < length`` elementwise."""
+    W = counts_ref.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    count = jnp.zeros(shape, jnp.int32)
+    for i in range(P):
+        e = g * P + i
+        c = jnp.where(e < W, counts_ref[b, jnp.minimum(e, W - 1)], 0)
+        count = jnp.where(col // bs == i, c, count)
+    return col % bs < count
 
-    @pl.when(count > 0)
-    def _step():
-        s = _scores(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, scale)
-        off = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _accumulate(jnp.where(off < count, s, NEG_INF), c_v_ref[0],
-                    acc_ref, m_ref, l_ref)
 
-    @pl.when(sb == num_sel - 1)
-    def _():
-        _finish(o_ref, acc_ref, l_ref)
+def _sparse_body(b, g, views, counts_ref, q_e_ref, q_lat_ref,
+                 acc_ref, m_ref, l_ref, *, scale: float, P: int, bs: int):
+    """``_paged_body`` over the selection table: the length mask becomes a
+    per-entry row count (``sel_counts``; 0 contributes nothing), so the
+    walk does O(k·block) work per token.  Selected blocks arrive in
+    ascending chain order; with the full chain selected the walk, the
+    groups, the mask and the accumulation order equal the dense kernel's
+    exactly (the bit-identity wall)."""
+    k_e, c_k, c_v = views
+    s = _scores(q_e_ref, q_lat_ref, k_e, c_k, scale)
+    ok = _count_mask(b, g, counts_ref, s.shape, P, bs)
+    _accumulate(jnp.where(ok, s, NEG_INF), c_v, acc_ref, m_ref, l_ref)
 
 
 def elite_decode_sparse_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -387,44 +544,22 @@ def elite_decode_sparse_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     nkv = _check_heads(q_e, k_e_pages, q_group)
     B, W = sel_tables.shape
     assert q_e.shape[0] == B and sel_counts.shape == (B, W)
+    P = pages_per_step(block_size, W)
     return _paged_call(
-        functools.partial(_sparse_kernel, scale=scale, num_sel=W),
-        "elite_decode_sparse_paged", (sel_tables, sel_counts),
-        _block_diag_q_e(q_e, nkv), q_lat,
-        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages),
-        c_v_pages.dtype, interpret)
+        functools.partial(_sparse_body, scale=scale, P=P, bs=block_size),
+        "elite_decode_sparse_paged", sel_tables, _selected_pages(sel_counts),
+        (sel_counts,), _block_diag_q_e(q_e, nkv), q_lat,
+        _streams(block_size, k_e_pages, c_k_pages, c_v_pages), block_size,
+        c_k_pages.dtype, interpret)
 
 
-def _sparse_kernel_q8(sel_tables_ref,         # scalar-prefetch [B, W] int32
-                      sel_counts_ref,         # scalar-prefetch [B, W] int32
-                      q_e_ref, q_lat_ref, k_e_ref, c_k_ref, c_v_ref,
-                      k_s_ref, ck_s_ref, cv_s_ref,
-                      o_ref,
-                      acc_ref, m_ref, l_ref,
-                      *, scale: float, num_sel: int):
-    """``_sparse_kernel`` over int8 pages: the selection walk also pulls each
-    block's per-slot f32 scales, exactly like ``_paged_kernel_q8``."""
-    b = pl.program_id(0)
-    sb = pl.program_id(1)
-
-    @pl.when(sb == 0)
-    def _():
-        _init(acc_ref, m_ref, l_ref)
-
-    count = sel_counts_ref[b, sb]
-
-    @pl.when(count > 0)
-    def _step():
-        s = _scores_q8(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, k_s_ref,
-                       ck_s_ref, scale)
-        off = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _accumulate(jnp.where(off < count, s, NEG_INF),
-                    c_v_ref[0].astype(jnp.float32), acc_ref, m_ref, l_ref,
-                    cv_s_ref[0])
-
-    @pl.when(sb == num_sel - 1)
-    def _():
-        _finish(o_ref, acc_ref, l_ref)
+def _sparse_body_q8(b, g, views, counts_ref, q_e_ref, q_lat_ref,
+                    acc_ref, m_ref, l_ref, *, scale: float, P: int, bs: int):
+    """``_sparse_body`` over int8 pages, scaled like ``_paged_body_q8``."""
+    k_e, c_k, c_v, k_s, ck_s, cv_s = views
+    s = _scores_q8(q_e_ref, q_lat_ref, k_e, c_k, k_s, ck_s, scale)
+    ok = _count_mask(b, g, counts_ref, s.shape, P, bs)
+    _accumulate(jnp.where(ok, s, NEG_INF), c_v, acc_ref, m_ref, l_ref, cv_s)
 
 
 def elite_decode_sparse_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -440,57 +575,43 @@ def elite_decode_sparse_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     nkv = _check_heads(q_e, k_e_pages, q_group)
     B, W = sel_tables.shape
     assert q_e.shape[0] == B and sel_counts.shape == (B, W)
+    P = pages_per_step(block_size, W)
     return _paged_call(
-        functools.partial(_sparse_kernel_q8, scale=scale, num_sel=W),
-        "elite_decode_sparse_paged_q8", (sel_tables, sel_counts),
+        functools.partial(_sparse_body_q8, scale=scale, P=P, bs=block_size),
+        "elite_decode_sparse_paged_q8", sel_tables,
+        _selected_pages(sel_counts), (sel_counts,),
         _block_diag_q_e(q_e.astype(jnp.float32), nkv),
         q_lat.astype(jnp.float32),
-        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages,
-                    k_e_scale, c_k_scale, c_v_scale),
-        jnp.float32, interpret)
+        _streams(block_size, k_e_pages, c_k_pages, c_v_pages,
+                 (k_e_scale, c_k_scale, c_v_scale)),
+        block_size, jnp.float32, interpret)
 
 
 # ---------------------------------------------------------------------------
 # paged verify: k+1-token speculative windows, multi-query over the block table
 # ---------------------------------------------------------------------------
 
-def _verify_kernel(block_tables_ref,          # scalar-prefetch [B, mb] int32
-                   q_offsets_ref,             # scalar-prefetch [B] int32
-                   lengths_ref,               # scalar-prefetch [B] int32
-                   q_e_ref, q_lat_ref, k_e_ref, c_k_ref, c_v_ref,
-                   o_ref,
-                   acc_ref, m_ref, l_ref,
-                   *, block_size: int, scale: float, max_blocks: int,
-                   n_heads: int):
-    """``_paged_kernel`` generalized to ``window · nh`` query rows per lane:
-    row ``r`` holds window position ``w = r // nh`` whose global query
-    position is ``q_offsets[b] + w``, so the length mask gains the per-row
-    offset-causal term of ``flash_prefill``'s diagonal — speculative verify
-    scores all ``k+1`` window tokens in one block-table walk over the
-    compressed cache."""
-    b = pl.program_id(0)
-    sb = pl.program_id(1)
+def _window_mask(b, g, q_offsets_ref, lengths_ref, shape, span: int,
+                 n_heads: int):
+    """Row ``r`` holds window position ``w = r // nh`` at global position
+    ``q_offsets[b] + w``: it sees cache positions up to its own (the
+    offset-causal term of ``flash_prefill``'s diagonal) below the length."""
+    pos = _positions(g, span, shape)
+    qw = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // n_heads
+    return (pos <= q_offsets_ref[b] + qw) & (pos < lengths_ref[b])
 
-    @pl.when(sb == 0)
-    def _():
-        _init(acc_ref, m_ref, l_ref)
 
-    length = lengths_ref[b]
-    q_offset = q_offsets_ref[b]
-    start = sb * block_size
-
-    @pl.when(start < length)
-    def _step():
-        s = _scores(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, scale)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qw = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // n_heads
-        _accumulate(jnp.where((pos <= q_offset + qw) & (pos < length), s,
-                              NEG_INF),
-                    c_v_ref[0], acc_ref, m_ref, l_ref)
-
-    @pl.when(sb == max_blocks - 1)
-    def _():
-        _finish(o_ref, acc_ref, l_ref)
+def _verify_body(b, g, views, q_offsets_ref, lengths_ref, q_e_ref,
+                 q_lat_ref, acc_ref, m_ref, l_ref, *, scale: float,
+                 span: int, n_heads: int):
+    """``_paged_body`` generalized to ``window · nh`` query rows per lane,
+    so speculative verify scores all ``k+1`` window tokens in one walk over
+    the compressed cache."""
+    k_e, c_k, c_v = views
+    s = _scores(q_e_ref, q_lat_ref, k_e, c_k, scale)
+    ok = _window_mask(b, g, q_offsets_ref, lengths_ref, s.shape, span,
+                      n_heads)
+    _accumulate(jnp.where(ok, s, NEG_INF), c_v, acc_ref, m_ref, l_ref)
 
 
 def _window_rows(q_e, q_lat, nkv: int):
@@ -517,53 +638,26 @@ def elite_verify_paged(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     mb = block_tables.shape[1]
     assert block_tables.shape == (B, mb)
     assert q_offsets.shape == (B,) and lengths.shape == (B,)
+    span = pages_per_step(block_size, mb) * block_size
     out = _paged_call(
-        functools.partial(_verify_kernel, block_size=block_size, scale=scale,
-                          max_blocks=mb, n_heads=nh),
-        "elite_verify_paged", (block_tables, q_offsets, lengths),
+        functools.partial(_verify_body, scale=scale, span=span, n_heads=nh),
+        "elite_verify_paged", block_tables,
+        _chain_pages(lengths, block_size, mb), (q_offsets, lengths),
         *_window_rows(q_e, q_lat, nkv),
-        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages),
-        c_v_pages.dtype, interpret)
+        _streams(block_size, k_e_pages, c_k_pages, c_v_pages), block_size,
+        c_k_pages.dtype, interpret)
     return out.reshape(B, W, nh, -1)
 
 
-def _verify_kernel_q8(block_tables_ref,       # scalar-prefetch [B, mb] int32
-                      q_offsets_ref,          # scalar-prefetch [B] int32
-                      lengths_ref,            # scalar-prefetch [B] int32
-                      q_e_ref, q_lat_ref, k_e_ref, c_k_ref, c_v_ref,
-                      k_s_ref, ck_s_ref, cv_s_ref,
-                      o_ref,
-                      acc_ref, m_ref, l_ref,
-                      *, block_size: int, scale: float, max_blocks: int,
-                      n_heads: int):
-    """``_verify_kernel`` over int8 pages with fused dequant — same W·nh
-    query-row layout and offset-causal mask, same per-slot scale loads as
-    ``_paged_kernel_q8``."""
-    b = pl.program_id(0)
-    sb = pl.program_id(1)
-
-    @pl.when(sb == 0)
-    def _():
-        _init(acc_ref, m_ref, l_ref)
-
-    length = lengths_ref[b]
-    q_offset = q_offsets_ref[b]
-    start = sb * block_size
-
-    @pl.when(start < length)
-    def _step():
-        s = _scores_q8(q_e_ref, q_lat_ref, k_e_ref, c_k_ref, k_s_ref,
-                       ck_s_ref, scale)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qw = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // n_heads
-        _accumulate(jnp.where((pos <= q_offset + qw) & (pos < length), s,
-                              NEG_INF),
-                    c_v_ref[0].astype(jnp.float32), acc_ref, m_ref, l_ref,
-                    cv_s_ref[0])
-
-    @pl.when(sb == max_blocks - 1)
-    def _():
-        _finish(o_ref, acc_ref, l_ref)
+def _verify_body_q8(b, g, views, q_offsets_ref, lengths_ref, q_e_ref,
+                    q_lat_ref, acc_ref, m_ref, l_ref, *, scale: float,
+                    span: int, n_heads: int):
+    """``_verify_body`` over int8 pages, scaled like ``_paged_body_q8``."""
+    k_e, c_k, c_v, k_s, ck_s, cv_s = views
+    s = _scores_q8(q_e_ref, q_lat_ref, k_e, c_k, k_s, ck_s, scale)
+    ok = _window_mask(b, g, q_offsets_ref, lengths_ref, s.shape, span,
+                      n_heads)
+    _accumulate(jnp.where(ok, s, NEG_INF), c_v, acc_ref, m_ref, l_ref, cv_s)
 
 
 def elite_verify_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -581,16 +675,23 @@ def elite_verify_paged_q8(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     mb = block_tables.shape[1]
     assert block_tables.shape == (B, mb)
     assert q_offsets.shape == (B,) and lengths.shape == (B,)
+    span = pages_per_step(block_size, mb) * block_size
     out = _paged_call(
-        functools.partial(_verify_kernel_q8, block_size=block_size,
-                          scale=scale, max_blocks=mb, n_heads=nh),
-        "elite_verify_paged_q8", (block_tables, q_offsets, lengths),
+        functools.partial(_verify_body_q8, scale=scale, span=span,
+                          n_heads=nh),
+        "elite_verify_paged_q8", block_tables,
+        _chain_pages(lengths, block_size, mb), (q_offsets, lengths),
         *_window_rows(q_e.astype(jnp.float32), q_lat.astype(jnp.float32),
                       nkv),
-        _flat_pages(block_size, k_e_pages, c_k_pages, c_v_pages,
-                    k_e_scale, c_k_scale, c_v_scale),
-        jnp.float32, interpret)
+        _streams(block_size, k_e_pages, c_k_pages, c_v_pages,
+                 (k_e_scale, c_k_scale, c_v_scale)),
+        block_size, jnp.float32, interpret)
     return out.reshape(B, W, nh, -1)
+
+
+def _joint(c_k, c_v):
+    """``c_v`` for the XLA fallbacks: ``None`` names the joint latent."""
+    return c_k if c_v is None else c_v
 
 
 def elite_verify_paged_xla(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
@@ -599,6 +700,7 @@ def elite_verify_paged_xla(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     """Gather-based XLA fallback for the verify kernel (CPU / rejected
     shapes) — one gather of the compressed stream, then the dense multi-query
     oracle; identical semantics to the Pallas block-table walk."""
+    c_v_pages = _joint(c_k_pages, c_v_pages)
     from repro.kernels.ref import elite_verify_paged_ref
     return elite_verify_paged_ref(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
                                   block_tables, q_offsets, lengths, q_group,
@@ -612,6 +714,7 @@ def elite_decode_paged_xla(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
     kernel (used on CPU and for shapes the TPU lowering rejects).  One gather
     materializes [B, mb·block_size] of the compressed stream — still only the
     2r·n_kv + d_ckv floats/token the paper pays for, never the full K/V."""
+    c_v_pages = _joint(c_k_pages, c_v_pages)
     from repro.kernels.ref import elite_decode_paged_ref
     return elite_decode_paged_ref(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
                                   block_tables, lengths, q_group, scale,
@@ -624,6 +727,8 @@ def elite_decode_paged_q8_xla(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
                               scale: float, block_size: int):
     """XLA fallback for the int8 paged decode kernel: dequantize the pool
     (one multiply) then the gather-based f32 fallback — exact oracle match."""
+    c_v_pages, c_v_scale = (_joint(c_k_pages, c_v_pages),
+                            _joint(c_k_scale, c_v_scale))
     from repro.kernels.ref import elite_decode_paged_q8_ref
     return elite_decode_paged_q8_ref(q_e, q_lat, k_e_pages, c_k_pages,
                                      c_v_pages, k_e_scale, c_k_scale,
@@ -636,6 +741,8 @@ def elite_verify_paged_q8_xla(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
                               block_tables, q_offsets, lengths, q_group: int,
                               scale: float, block_size: int):
     """XLA fallback for the int8 paged verify kernel."""
+    c_v_pages, c_v_scale = (_joint(c_k_pages, c_v_pages),
+                            _joint(c_k_scale, c_v_scale))
     from repro.kernels.ref import elite_verify_paged_q8_ref
     return elite_verify_paged_q8_ref(q_e, q_lat, k_e_pages, c_k_pages,
                                      c_v_pages, k_e_scale, c_k_scale,
@@ -648,6 +755,7 @@ def elite_decode_sparse_paged_xla(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages,
                                   scale: float, block_size: int):
     """Gather-based XLA fallback for the sparse decode kernel: gather only
     the [B, W·block_size] selected slots, then the shared masked oracle."""
+    c_v_pages = _joint(c_k_pages, c_v_pages)
     from repro.kernels.ref import elite_decode_sparse_paged_ref
     return elite_decode_sparse_paged_ref(q_e, q_lat, k_e_pages, c_k_pages,
                                          c_v_pages, sel_tables, sel_counts,
@@ -660,6 +768,8 @@ def elite_decode_sparse_paged_q8_xla(q_e, q_lat, k_e_pages, c_k_pages,
                                      q_group: int, scale: float,
                                      block_size: int):
     """XLA fallback for the int8 sparse decode kernel."""
+    c_v_pages, c_v_scale = (_joint(c_k_pages, c_v_pages),
+                            _joint(c_k_scale, c_v_scale))
     from repro.kernels.ref import elite_decode_sparse_paged_q8_ref
     return elite_decode_sparse_paged_q8_ref(q_e, q_lat, k_e_pages, c_k_pages,
                                             c_v_pages, k_e_scale, c_k_scale,

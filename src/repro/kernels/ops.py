@@ -315,7 +315,9 @@ def _shard_map(fn, mesh, in_specs, out_specs):
 
 
 def _rep(x) -> _P:
-    return _P(*([None] * x.ndim))
+    """Replicated spec of ``x`` (``None``, the joint latent's absent
+    ``c_v`` stream or scale, has no leaves to place)."""
+    return _P() if x is None else _P(*([None] * x.ndim))
 
 
 def elite_decode_paged_tp(q_e, q_lat, k_e_pages, c_k_pages, c_v_pages, scales,

@@ -103,6 +103,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.cache import BlockManager, OutOfBlocks, PagedKVPool
+from repro.kernels.elite_decode import pages_per_step
 from repro.models import lm
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER
@@ -626,6 +627,13 @@ class Scheduler:
         self._m_prefill_capacity = m.counter(
             "serve_prefill_capacity_tokens_total",
             "token rows of the prefill calls made, padding included")
+        self._m_decode_pages = m.counter(
+            "serve_decode_pages_walked_total",
+            "pool pages the paged decode kernel walks per layer, summed over "
+            "decode calls (each lane's live groups rounded up to P pages)")
+        self._m_decode_table_pages = m.counter(
+            "serve_decode_table_pages_total",
+            "block-table entries of those decode calls (lanes x max_blocks)")
         self._m_preemptions = m.counter(
             "serve_preemptions_total", "residents evicted on OutOfBlocks")
         self._m_swap_outs = m.counter(
@@ -1338,6 +1346,11 @@ class Scheduler:
         self._step_wall_ms.append((time.perf_counter() - t0) * 1e3)
         self._m_step_ms.observe(self._step_wall_ms[-1])
         self._lane_steps += len(active)
+        mb = scfg.max_blocks_per_seq
+        P = pages_per_step(scfg.block_size, mb)
+        self._m_decode_pages.inc(
+            int(np.sum(-(-lengths // (P * scfg.block_size)))) * P)
+        self._m_decode_table_pages.inc(B * mb)
         if scfg.sparse_topk_blocks > 0:
             # the forward already ran the selection on device; mirror the
             # arithmetic here (ceil-div resident chain vs. selection width)

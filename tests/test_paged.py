@@ -90,6 +90,68 @@ def test_paged_decode_separate_cv():
                                atol=3e-5, rtol=3e-5)
 
 
+# The grouped walk: 16-token pages, P = 256 // 16 = 16 pages per step, a
+# 40-entry table (not a multiple of P: groups of 16, 16 and 8 entries).
+GROUP_BS, GROUP_MB = 16, 40
+GROUP_LENGTHS = [
+    0,                           # dead lane: fetches nothing, writes zeros
+    1,
+    256,                         # exactly one group
+    0,                           # the next live lane's prefetch skips it
+    256 + 17,                    # ends mid-way through the second group
+    512,                         # exactly on the second group's edge
+    300,
+    GROUP_MB * GROUP_BS,         # fills the whole table (short last group)
+    0,                           # dead last lane: no prefetch past the end
+]
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "q8"])
+@pytest.mark.parametrize("joint", [True, False], ids=["joint_c", "c_k_c_v"])
+def test_grouped_walk_vs_ref(joint, quantized):
+    """The grouped live-page walk against the paged oracle: lengths ending
+    mid-group and on a group edge, an empty lane, a lane filling the whole
+    table, a table that is not a multiple of P; a joint latent (one stream,
+    ``c_v_pages=None``) and separate c_k/c_v streams; f32 and int8 pools."""
+    from repro.core import quant
+    bs, mb = GROUP_BS, GROUP_MB
+    assert ed.pages_per_step(bs, mb) == 16 and mb % 16
+    B = len(GROUP_LENGTHS)
+    q_e, q_lat, k_pages, c_k, bt, lens = _paged_case(
+        7, B, 2, 2, 4, 16, bs, pool_blocks=160, mb=mb, lengths=GROUP_LENGTHS)
+    c_v = c_k if joint else jax.random.normal(jax.random.PRNGKey(8),
+                                              c_k.shape)
+    if quantized:
+        (k_q, k_s), (ck_q, ck_s), (cv_q, cv_s) = (
+            quant.quantize_rows(x) for x in (k_pages, c_k, c_v))
+        o_ref = ref.elite_decode_paged_q8_ref(q_e, q_lat, k_q, ck_q, cv_q,
+                                              k_s, ck_s, cv_s, bt, lens, 2,
+                                              0.2, bs)
+        if joint:
+            cv_q = cv_s = None
+        o_pal = ed.elite_decode_paged_q8(q_e, q_lat, k_q, ck_q, cv_q, k_s,
+                                         ck_s, cv_s, bt, lens, 2, 0.2, bs,
+                                         interpret=True)
+    else:
+        o_ref = ref.elite_decode_paged_ref(q_e, q_lat, k_pages, c_k, c_v,
+                                           bt, lens, 2, 0.2, bs)
+        o_pal = ed.elite_decode_paged(q_e, q_lat, k_pages, c_k,
+                                      None if joint else c_v, bt, lens, 2,
+                                      0.2, bs, interpret=True)
+    np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
+                               atol=3e-5, rtol=3e-5)
+    for b in (0, 3, 8):
+        assert float(jnp.max(jnp.abs(o_pal[b]))) == 0.0
+    if joint and not quantized:
+        # one stream read once serves both roles: the same numbers as the
+        # same array passed as two streams
+        np.testing.assert_array_equal(
+            np.asarray(o_pal),
+            np.asarray(ed.elite_decode_paged(q_e, q_lat, k_pages, c_k, c_k,
+                                             bt, lens, 2, 0.2, bs,
+                                             interpret=True)))
+
+
 def test_paged_matches_contiguous_kernel():
     """A paged layout whose chain is the identity must equal the contiguous
     dense kernel on the same data — the layouts describe the same cache."""

@@ -199,6 +199,45 @@ def test_prefill_call_counters(tiny_elite_cfg, tiny_elite_model, chunk):
     assert live == sum(len(r.prompt) for r in reqs) <= capacity
 
 
+def test_decode_page_counters(tiny_elite_cfg, tiny_elite_model):
+    """The decode page counters follow the lanes' lengths of every decode
+    call: pages walked = Σ cdiv(len, P·bs)·P (live groups rounded up to P),
+    table pages = Σ B·max_blocks; their ratio is the share of the table
+    the grouped walk touches.  Prompts of 250–258 tokens put lanes in one
+    group and then two as they decode."""
+    from repro.kernels.elite_decode import pages_per_step
+    params, buffers = tiny_elite_model
+    reg = MetricsRegistry()
+    scfg = serve_loop.SchedulerConfig(
+        max_slots=2, block_size=4, num_blocks=160, max_len=512,
+        prefill_bucket=64, prefill_chunk_tokens=64)
+    sched = serve_loop.Scheduler(params, buffers, tiny_elite_cfg, scfg,
+                                 metrics=reg)
+    calls = []
+    decode = sched._decode
+
+    def recording(*args):
+        calls.append(np.asarray(args[-1]))          # the lanes' lengths
+        return decode(*args)
+
+    sched._decode = recording
+    rng = np.random.default_rng(3)
+    sched.run([serve_loop.Request(
+        uid=i, prompt=rng.integers(0, tiny_elite_cfg.vocab_size,
+                                   250 + 8 * i).astype(np.int32),
+        max_new_tokens=6, arrival=0.0) for i in range(2)])
+    mb, bs = scfg.max_blocks_per_seq, scfg.block_size
+    P = pages_per_step(bs, mb)
+    assert (mb, P) == (128, 64)
+    groups = [-(-ln // (P * bs)) for ln in calls]
+    assert {1, 2} <= {int(g) for ln, g in zip(calls, groups)
+                      for g in g[ln > 0]}
+    walked = sum(int(np.sum(g)) * P for g in groups)
+    assert reg.get("serve_decode_pages_walked_total").value == walked
+    assert (reg.get("serve_decode_table_pages_total").value
+            == len(calls) * scfg.max_slots * mb)
+
+
 def test_router_replicas_time_their_own_steps(tiny_elite_cfg,
                                               tiny_elite_model):
     """The router drives each replica's ``step()`` with no accounting of

@@ -56,36 +56,78 @@ def _spec(one_chip, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-def _paged_args(one_chip, kernel):
+#: lanes, query heads, kv heads, 2r, d_c, table entries, pool blocks
+PAGED_WIDTHS = {
+    "tinyllama": (B, NH, NKV, R2, DC, MB, N_BLOCKS),  # streams under 128 lanes
+    "yi6b_docqa": (64, 32, 4, 32, 128, 256, 16384),
+    "granite2b_chat": (128, 32, 8, 16, 128, 128, 10240),
+}
+
+
+def _paged_args(one_chip, kernel, widths):
+    b, nh, nkv, r2, dc, mb, n_blocks = widths
     s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)
-    n_slots = N_BLOCKS * BS
+    n_slots = n_blocks * BS
     q8 = kernel.endswith("_q8")
     pdt = jnp.int8 if q8 else jnp.float32
-    pages = [s((n_slots, NKV * R2), pdt), s((n_slots, DC), pdt),
-             s((n_slots, DC), pdt)]
+    pages = [s((n_slots, nkv * r2), pdt), s((n_slots, dc), pdt),
+             s((n_slots, dc), pdt)]
     pages += [s((n_slots,))] * 3 if q8 else []
     if kernel.startswith("elite_verify"):
-        q = [s((B, W, NH, R2)), s((B, W, NH, DC))]
+        q = [s((b, W, nh, r2)), s((b, W, nh, dc))]
     else:
-        q = [s((B, NH, R2)), s((B, NH, DC))]
+        q = [s((b, nh, r2)), s((b, nh, dc))]
     if kernel.startswith("elite_decode_sparse"):
-        walk = [s((B, 8), jnp.int32), s((B, 8), jnp.int32)]
+        walk = [s((b, 8), jnp.int32), s((b, 8), jnp.int32)]
     elif kernel.startswith("elite_verify"):
-        walk = [s((B, MB), jnp.int32), s((B,), jnp.int32), s((B,), jnp.int32)]
+        walk = [s((b, mb), jnp.int32), s((b,), jnp.int32), s((b,), jnp.int32)]
     else:
-        walk = [s((B, MB), jnp.int32), s((B,), jnp.int32)]
+        walk = [s((b, mb), jnp.int32), s((b,), jnp.int32)]
     return q + pages + walk
 
 
-@pytest.mark.parametrize("kernel", [
+PAGED_KERNELS = [
     "elite_decode_paged", "elite_decode_paged_q8",
     "elite_decode_sparse_paged", "elite_decode_sparse_paged_q8",
     "elite_verify_paged", "elite_verify_paged_q8",
-])
-def test_paged_kernel_compiles_for_v5e(one_chip, kernel):
+]
+
+
+def _compile_paged(one_chip, kernel, widths):
     fn = getattr(ed, kernel)
-    step = jax.jit(lambda *a: fn(*a, q_group=G, scale=0.125, block_size=BS))
-    compiled = step.lower(*_paged_args(one_chip, kernel)).compile()
+    nh, nkv = PAGED_WIDTHS[widths][1:3]
+    step = jax.jit(lambda *a: fn(*a, q_group=nh // nkv, scale=0.125,
+                                 block_size=BS))
+    compiled = step.lower(*_paged_args(one_chip, kernel,
+                                       PAGED_WIDTHS[widths])).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", PAGED_KERNELS)
+def test_paged_kernel_compiles_for_v5e(one_chip, kernel):
+    _compile_paged(one_chip, kernel, "tinyllama")
+
+
+@pytest.mark.parametrize("widths", ["yi6b_docqa", "granite2b_chat"])
+@pytest.mark.parametrize("kernel", PAGED_KERNELS)
+def test_paged_kernel_compiles_at_cell_widths(one_chip, kernel, widths):
+    """The benchmark cells' widths: docqa's 64 lanes over 256 table
+    entries, chat's 128 lanes over 128 (separate latent streams; the
+    joint-latent decode is the next test)."""
+    _compile_paged(one_chip, kernel, widths)
+
+
+@pytest.mark.parametrize("widths", ["yi6b_docqa", "granite2b_chat"])
+def test_joint_latent_decode_compiles_for_v5e(one_chip, widths):
+    """The serving path's call: one joint ``c`` stream (``c_v_pages=None``),
+    fetched once per page for both roles."""
+    nh, nkv = PAGED_WIDTHS[widths][1:3]
+    q_e, q_lat, k_e, c, _, bt, ln = _paged_args(
+        one_chip, "elite_decode_paged", PAGED_WIDTHS[widths])
+    step = jax.jit(lambda q_e, q_lat, k_e, c, bt, ln: ed.elite_decode_paged(
+        q_e, q_lat, k_e, c, None, bt, ln, q_group=nh // nkv, scale=0.125,
+        block_size=BS))
+    compiled = step.lower(q_e, q_lat, k_e, c, bt, ln).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
